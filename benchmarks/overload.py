@@ -343,7 +343,9 @@ def bench_serving_recovery() -> List[Row]:
     report the recovery wall time (journal replay + checkpoint load +
     pool rebuild + replayed decode rounds), the rounds replayed, and the
     preserved-vs-lost token split (lost = emitted after the checkpoint,
-    regenerated bitwise by deterministic replay — never silently gone)."""
+    regenerated bitwise by deterministic replay — never silently gone).
+    Both children are pinned to the CPU (the parent may hold the chip,
+    which one process owns at a time), so the rows say platform=cpu."""
     import json
     import os
     import subprocess
@@ -351,7 +353,7 @@ def bench_serving_recovery() -> List[Row]:
     import tempfile
 
     root = tempfile.mkdtemp(prefix="bench_recovery_")
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (os.path.abspath("src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     crash = subprocess.run(
@@ -371,15 +373,16 @@ def bench_serving_recovery() -> List[Row]:
     if r["completed"] != 4:
         raise RuntimeError(f"recovery lost requests: {r}")
     return [
-        ("recovery: wall time (SIGKILL -> drained)", r["wall_s"], "s"),
+        ("recovery: wall time (SIGKILL -> drained)", r["wall_s"],
+         "s;platform=cpu"),
         ("recovery: rounds replayed", float(r["rounds_replayed"]),
-         "rounds"),
+         "rounds;platform=cpu"),
         ("recovery: tokens preserved (checkpointed)",
-         float(r["tokens_preserved"]), "tokens"),
+         float(r["tokens_preserved"]), "tokens;platform=cpu"),
         ("recovery: tokens replayed (post-ckpt, regenerated)",
-         float(r["tokens_replayed"]), "tokens"),
+         float(r["tokens_replayed"]), "tokens;platform=cpu"),
         ("recovery: requests completed after restart",
-         float(r["completed"]), "requests"),
+         float(r["completed"]), "requests;platform=cpu"),
     ]
 
 

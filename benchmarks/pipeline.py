@@ -688,26 +688,27 @@ def bench_serving_mesh() -> List[Row]:
     same eight-request greedy workload, but the 1x8 engine runs 2x the slot
     capacity while each device holds 1/8 of the KV pool.  Spawned as a
     subprocess because the mesh needs 8 host devices and XLA_FLAGS is fixed
-    at interpreter start (the bench parent may be running on one device)."""
+    at interpreter start.  The child is pinned to the CPU (the parent may
+    hold the chip, which one process owns at a time), so its rows are
+    labelled platform=cpu; a failed child raises."""
     import json
     import os
     import subprocess
     import sys
 
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", _MESH_CHILD], env=env,
                           capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
-        return [("pipeline/serving_mesh_error", float("nan"),
-                 proc.stderr.strip().splitlines()[-1][:120]
-                 if proc.stderr.strip() else "child failed")]
+        raise RuntimeError(f"mesh serving child exited {proc.returncode}:\n"
+                           f"{proc.stderr[-2000:]}")
     rep = json.loads(proc.stdout.strip().splitlines()[-1])
     cap_ratio = rep["mesh_capacity"] / rep["base_capacity"]
     shard_frac = rep["pool_bytes_shard"] / rep["pool_bytes_full"]
-    tag = (f"token_exact={rep['token_exact']};mesh=1x8;"
+    tag = (f"platform=cpu;token_exact={rep['token_exact']};mesh=1x8;"
            f"decode_traces={rep['decode_traces']}")
     return [
         ("pipeline/serving_mesh_base_cap4_drain", rep["base_s"] * 1e6,

@@ -15,7 +15,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.obs.telemetry import get_telemetry
 
@@ -29,6 +29,10 @@ DEFAULT_RULES = {
     "seq": ("model",),                 # Megatron-SP sequence sharding
     "vocab": ("model",),
     "heads": ("model",),               # flattened (H*dh) or head axis
+    # head axis of the attention output entering wo: training keeps it on
+    # "model" (a row-parallel wo, merged by psum); serving has no rule for
+    # it, so the heads are all-gathered first and wo sees whole rows
+    "attn_out": ("model",),
     "kv": ("model",),
     "ff": ("model",),
     "expert": ("model",),
@@ -162,7 +166,8 @@ def parse_mesh(spec: Optional[str]) -> Optional[Mesh]:
     if n_dev > len(jax.devices()):
         raise ValueError(
             f"mesh {spec} needs {n_dev} devices, have {len(jax.devices())}")
-    return jax.make_mesh(extents, names)
+    return jax.make_mesh(extents, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def serving_sharder(mesh: Optional[Mesh]) -> Sharder:

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -73,8 +75,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: Optional[int] = None,
                            block_q: int = 512, block_kv: int = 512,
-                           interpret: bool = True):
-    """q: (B, Sq, Hq, D); k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D)."""
+                           interpret: Optional[bool] = None):
+    """q: (B, Sq, Hq, D); k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D).
+    ``interpret`` defaults to the platform's choice
+    (:func:`repro.kernels.ops.resolve_interpret`)."""
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     assert Hq % Hkv == 0
@@ -108,6 +112,6 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qh, kh, vh)
     return jnp.moveaxis(out, 1, 2)

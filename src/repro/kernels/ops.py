@@ -1,8 +1,11 @@
-"""jit'd dispatch wrappers for the kernel package.
+"""jit'd dispatch wrappers for the kernel package, and the one place that
+decides how Pallas kernels run on this process's platform.
 
-``use_pallas(True)`` (or REPRO_USE_PALLAS=1) routes to the Pallas TPU kernels
-(executed in interpret mode on CPU); otherwise the pure-jnp references run.
-The model/risk stacks only ever import from here.
+On a TPU the Pallas kernels run compiled and are the default path; elsewhere
+the pure-jnp references run unless ``use_pallas(True)`` (or
+REPRO_USE_PALLAS=1) routes to the kernels, which then execute in Pallas
+interpret mode.  Interpret mode is reachable on the CPU only: asking for it
+on a TPU raises.  The model/risk stacks only ever import from here.
 """
 from __future__ import annotations
 
@@ -43,8 +46,7 @@ def _ssd_pallas_vjp(chunk: int, interpret: bool):
     f.defvjp(fwd, bwd)
     return f
 
-# single source of truth for aggregate_loss lookup strategies; the kernel
-# table in kernels/aggregate_loss.py is checked against it at import
+# single source of truth for aggregate_loss lookup strategies
 AGG_VARIANTS = ("gather", "onehot")
 
 
@@ -59,23 +61,56 @@ def _env_agg_variant() -> str:
     return v
 
 
-_STATE = {"pallas": os.environ.get("REPRO_USE_PALLAS", "0") == "1",
-          "interpret": True,
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas interpret mode for this platform.  ``None`` picks compiled
+    kernels on a TPU and the interpreter elsewhere; ``True`` on a TPU is an
+    error, so no TPU code path runs a kernel in the interpreter.
+    ``False`` is honoured everywhere (it is how a kernel is compiled for a
+    described TPU from a CPU host)."""
+    tpu = on_tpu()
+    if interpret is None:
+        return not tpu
+    if interpret and tpu:
+        raise ValueError("Pallas interpret mode is CPU-only; on a TPU the "
+                         "kernels run compiled (interpret=None or False)")
+    return bool(interpret)
+
+
+def default_kernel_backend() -> str:
+    """Paged-attention backend for serving: the fused Pallas kernels on a
+    TPU, the jnp gather reference elsewhere."""
+    return "pallas" if on_tpu() else "jnp"
+
+
+def _env_pallas() -> Optional[bool]:
+    v = os.environ.get("REPRO_USE_PALLAS")
+    return None if v is None else v == "1"
+
+
+# "pallas"/"interpret" hold an explicit choice, or None for the platform's
+_STATE = {"pallas": _env_pallas(),
+          "interpret": None,
           "agg_variant": _env_agg_variant()}
 
 
-def use_pallas(on: bool, interpret: bool = True) -> None:
+def use_pallas(on: Optional[bool], interpret: Optional[bool] = None) -> None:
+    """Route ops to the Pallas kernels (``True``), the jnp references
+    (``False``) or the platform default (``None``)."""
     _STATE["pallas"] = on
     _STATE["interpret"] = interpret
 
 
 def pallas_enabled() -> bool:
-    return _STATE["pallas"]
+    return on_tpu() if _STATE["pallas"] is None else _STATE["pallas"]
 
 
 def use_aggregate_variant(name: str) -> None:
-    """Select the aggregate_loss Pallas lookup strategy: "gather" (per-lane
-    jnp.take) or "onehot" (gather-free one-hot x ELT matmul on the MXU).
+    """Select the aggregate_loss Pallas lookup strategy: "gather" (per-event
+    row DMA) or "onehot" (gather-free one-hot x ELT matmul on the MXU).
     Also settable via REPRO_AGG_VARIANT.  No effect on the jnp reference
     path, which is lookup-strategy-free."""
     if name not in AGG_VARIANTS:
@@ -101,12 +136,12 @@ def ssd(x, dt, a_log_decay, B, C, chunk: int,
         x, dt, a_log_decay, B, C = map(padL, (x, dt, a_log_decay, B, C))
         y, h = ssd(x, dt, a_log_decay, B, C, chunk, initial_state)
         return y[:, :L], h
-    if _STATE["pallas"]:
+    if pallas_enabled():
         b, _, H, P = x.shape
         N = B.shape[-1]
         h0 = (initial_state if initial_state is not None
               else jnp.zeros((b, H, P, N), jnp.float32))
-        fn = _ssd_pallas_vjp(chunk, _STATE["interpret"])
+        fn = _ssd_pallas_vjp(chunk, resolve_interpret(_STATE["interpret"]))
         return fn(x, dt, a_log_decay, B, C, h0)
     return _ref.ssd_chunked_ref(x, dt, a_log_decay, B, C, chunk,
                                 initial_state=initial_state)
@@ -129,7 +164,7 @@ def aggregate_loss(event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,
     pad = (-K) % chunk
     if pad:
         event_ids = jnp.pad(event_ids, ((0, 0), (0, pad)))
-    if _STATE["pallas"]:
+    if pallas_enabled():
         from repro.kernels import aggregate_loss as _agg
         return _agg.aggregate_loss_pallas(
             event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,

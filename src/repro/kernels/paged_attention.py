@@ -35,10 +35,10 @@ practice and ``tests/test_paged_attention.py`` locks both levels in.  The
 scatter kernel performs no arithmetic beyond the storage cast and is
 bit-exact with the jnp path.
 
-On CPU these run in interpret mode (``interpret=True``), where wall time is
-an emulation artefact — the structural win (bytes moved per round) is what
-``benchmarks/pipeline.py:bench_paged_attention`` tracks there; on a real
-TPU the index-mapped DMAs are the point.
+``interpret`` defaults to the platform's choice
+(:func:`repro.kernels.ops.resolve_interpret`): compiled on a TPU, where the
+index-mapped DMAs are the point, and interpreted on the CPU, where wall time
+is an emulation artefact and only correctness is checked.
 """
 from __future__ import annotations
 
@@ -50,6 +50,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
+
+from repro.kernels.ops import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -74,7 +77,7 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     q = q_ref[0].astype(jnp.float32).reshape(hkv, rep, d)   # (Hkv, rep, D)
     k = k_ref[0].astype(jnp.float32)                        # (P, Hkv, D)
     v = v_ref[0].astype(jnp.float32)
-    kpos = kpos_ref[0]                                      # (P,)
+    kpos = kpos_ref[...]                                    # (1, P)
 
     s = jnp.einsum("krd,pkd->krp", q, k,
                    preferred_element_type=jnp.float32) * scale
@@ -84,7 +87,7 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     valid = kpos <= pos
     if window is not None:
         valid &= kpos > pos - window
-    s = s + jnp.where(valid, 0.0, NEG_INF)[None, None, :]
+    s = s + jnp.where(valid, 0.0, NEG_INF)[None]
 
     m_prev, l_prev, acc = m_scr[...], l_scr[...], acc_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -107,7 +110,7 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
 
 def paged_attention_decode_pallas(q, k_pool, v_pool, pos_pool, page_table,
                                   positions, *, window: Optional[int] = None,
-                                  interpret: bool = True):
+                                  interpret: Optional[bool] = None):
     """Fused single-token GQA decode read against a paged KV pool.
 
     q: (C, H, D) compute dtype (already roped, this step's K/V already
@@ -118,10 +121,11 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, pos_pool, page_table,
 
     Each row streams only the NB pages its table names; table padding
     points at the SENTINEL page whose positions mask it out, so ragged
-    rings need no per-row block count.
+    rings need no per-row block count.  The position plane is read as
+    (NP, 1, P) so each page's block is a whole (1, P) tile.
     """
     C, H, D = q.shape
-    _, P, Hkv, _ = k_pool.shape
+    NP, P, Hkv, _ = k_pool.shape
     NB = page_table.shape[1]
     rep = H // Hkv
     kernel = functools.partial(
@@ -136,7 +140,7 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, pos_pool, page_table,
                          lambda c, j, pt, ps: (pt[c, j], 0, 0, 0)),
             pl.BlockSpec((1, P, Hkv, D),
                          lambda c, j, pt, ps: (pt[c, j], 0, 0, 0)),
-            pl.BlockSpec((1, P), lambda c, j, pt, ps: (pt[c, j], 0)),
+            pl.BlockSpec((None, 1, P), lambda c, j, pt, ps: (pt[c, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda c, j, pt, ps: (c, 0, 0)),
         scratch_shapes=[
@@ -148,8 +152,8 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, pos_pool, page_table,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, H, D), jnp.float32),
-        interpret=interpret,
-    )(page_table, positions, q, k_pool, v_pool, pos_pool)
+        interpret=resolve_interpret(interpret),
+    )(page_table, positions, q, k_pool, v_pool, pos_pool.reshape(NP, 1, P))
 
 
 # ---------------------------------------------------------------------------
@@ -161,7 +165,7 @@ def _scatter_kernel(pages_ref, kv_ref, pool_in_ref, pool_out_ref):
 
 
 def paged_prefill_scatter_pallas(pool, pages, values, *,
-                                 interpret: bool = True):
+                                 interpret: Optional[bool] = None):
     """Write prefill KV blocks into their allocated physical pages.
 
     pool: (S, NP, P, Hkv, D) storage dtype; pages: (nb,) int32 distinct
@@ -190,7 +194,7 @@ def paged_prefill_scatter_pallas(pool, pages, values, *,
         _scatter_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},     # pool (after the scalar operand)
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pages, values, pool)
 
 
@@ -216,14 +220,13 @@ def _model_axis(sh) -> int:
 def paged_attention_decode_sharded(q, k_pool, v_pool, pos_pool, page_table,
                                    positions, sh, *,
                                    window: Optional[int] = None,
-                                   interpret: bool = True):
+                                   interpret: Optional[bool] = None):
     """:func:`paged_attention_decode_pallas` partitioned along KV heads."""
     if _model_axis(sh) == 1:
         return paged_attention_decode_pallas(
             q, k_pool, v_pool, pos_pool, page_table, positions,
             window=window, interpret=interpret)
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    P = PartitionSpec
     H, Hkv = q.shape[1], k_pool.shape[2]
     if sh.extent("kv", Hkv) > 1:
         q_spec, pool_spec = P(None, "model", None), P(None, None, "model", None)
@@ -233,31 +236,30 @@ def paged_attention_decode_sharded(q, k_pool, v_pool, pos_pool, page_table,
         out_spec = P(None, "model", None)
     else:
         q_spec = pool_spec = out_spec = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(paged_attention_decode_pallas,
                           window=window, interpret=interpret),
         mesh=sh.mesh,
         in_specs=(q_spec, pool_spec, pool_spec, P(), P(), P()),
-        out_specs=out_spec, check_rep=False)
+        out_specs=out_spec, check_vma=False)
     return fn(q, k_pool, v_pool, pos_pool, page_table, positions)
 
 
 def paged_prefill_scatter_sharded(pool, pages, values, sh, *,
-                                  interpret: bool = True):
+                                  interpret: Optional[bool] = None):
     """:func:`paged_prefill_scatter_pallas` partitioned along KV heads."""
     if _model_axis(sh) == 1:
         return paged_prefill_scatter_pallas(pool, pages, values,
                                             interpret=interpret)
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    P = PartitionSpec
     Hkv = pool.shape[3]
     if sh.extent("kv", Hkv) > 1:
         kv_spec = P(None, None, None, "model", None)
     else:
         kv_spec = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(paged_prefill_scatter_pallas, interpret=interpret),
         mesh=sh.mesh,
         in_specs=(kv_spec, P(), kv_spec),
-        out_specs=kv_spec, check_rep=False)
+        out_specs=kv_spec, check_vma=False)
     return fn(pool, pages, values)

@@ -6,16 +6,17 @@ touches jax device state — the dry-run must set XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
     """Whatever devices exist locally, as a 1D 'data' mesh (tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

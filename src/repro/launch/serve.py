@@ -1,12 +1,15 @@
 """Multi-tenant serving driver.
 
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
-        --reduced --tenants 3 --requests 12 --mode continuous
+        --tenants 3 --requests 12 --mode continuous
 
-Builds a reduced model, spins up the multi-tenant scheduler and drains a
-synthetic request mix, printing per-tenant utilisation (the serving analogue
-of the paper's Fig 14 utilisation table) plus the realised staging/decode
-overlap pairs.  ``--mode`` selects the schedule:
+Builds the model (``--reduced``, the default, cuts it to CPU-test widths;
+``--no-reduced`` keeps the published widths), spins up the multi-tenant
+scheduler and drains a synthetic request mix, printing per-tenant
+utilisation (the serving analogue of the paper's Fig 14 utilisation table)
+plus the realised staging/decode overlap pairs.  :func:`build_stack` and
+:func:`drain` are the pieces ``chip_smoke.py`` shares with :func:`main`.
+``--mode`` selects the schedule:
 
 * ``continuous`` — continuous batching over a persistent slot table with a
   paged KV-cache: requests are admitted into an in-flight decode (same
@@ -14,10 +17,11 @@ overlap pairs.  ``--mode`` selects the schedule:
   ``--prefix-sharing`` common prompt prefixes map onto existing pages with
   copy-on-write) and retired rows are evicted, so the device never drains
   between tenant batches (also prints micro-round occupancy and
-  page-sharing stats).  ``--kernel-backend pallas`` swaps the decode
-  round's dense KV gather for the fused page-streaming Pallas kernels
-  (token-exact; interpret mode on CPU, where it demonstrates structure,
-  not speed).  Overload knobs: ``--priority K`` marks every K-th request
+  page-sharing stats).  The decode round reads the pool through the fused
+  page-streaming Pallas kernels on a TPU and the dense jnp gather
+  elsewhere; ``--kernel-backend`` overrides that (pallas runs in interpret
+  mode on the CPU, where it demonstrates structure, not speed).  Overload
+  knobs: ``--priority K`` marks every K-th request
   tier 0, ``--swap`` (default on) lets blocked tier-0 arrivals preempt
   tier-1 rows via host-tier KV swap (token-exact restore), and
   ``--max-backlog N`` sheds the lowest-priority queued work past N with
@@ -71,17 +75,21 @@ stack is built; without them telemetry stays disabled and costs nothing.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import ArchConfig, get_config
 from repro.core.tenancy import TenancyConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as pp
 from repro.models.model import build_model
 from repro.serving.engine import ServingEngine
-from repro.serving.multitenant import MODES, MultiTenantScheduler, Request
+from repro.serving.multitenant import (MODES, MultiTenantScheduler, Request,
+                                       Response)
 
 
 def list_archs() -> int:
@@ -121,14 +129,17 @@ def synth_extra_inputs(cfg, rng) -> dict:
     return extra
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--list-archs", action="store_true",
                     help="print the per-arch serving capability table "
                          "(modes, state kinds, preemptable, prefix "
                          "sharing, exactness class) and exit")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="cut the arch to CPU-test widths (d_model 64); "
+                         "--no-reduced serves it at its published widths")
     ap.add_argument("--tenants", type=int, default=3)
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -156,12 +167,13 @@ def main(argv=None) -> int:
                     help="continuous mode: batch same-bucket admissions "
                          "into one prefill call")
     ap.add_argument("--kernel-backend", choices=("jnp", "pallas"),
-                    default="jnp",
+                    default=None,
                     help="paged-attention backend: 'jnp' gathers each "
                          "row's dense logical window per decode step (A/B "
                          "baseline), 'pallas' streams pages in place "
-                         "through the fused kernels (interpret mode on "
-                         "CPU)")
+                         "through the fused kernels (compiled on a TPU, "
+                         "interpret mode on the CPU).  Default: pallas on "
+                         "a TPU, jnp elsewhere")
     ap.add_argument("--preserve-pristine", choices=("never", "reuse",
                                                     "always"),
                     default="reuse",
@@ -244,21 +256,23 @@ def main(argv=None) -> int:
                     help="print a one-line telemetry summary every N "
                          "scheduling steps (enables the telemetry plane; "
                          "0 = never)")
-    args = ap.parse_args(argv)
-    if args.list_archs:
-        return list_archs()
-    mode = args.mode or ("blocking" if args.blocking else "overlapped")
+    return ap
 
-    from repro.obs import TELEMETRY
-    obs_on = bool(args.trace_out or args.metrics_out or args.stats_every)
-    if obs_on:
-        # light the global plane before the stack is built so every layer
-        # (engine, scheduler, pool, swap store, staging lanes) records
-        TELEMETRY.enable()
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+def serve_mode(args) -> str:
+    return args.mode or ("blocking" if args.blocking else "overlapped")
+
+
+def build_stack(args, cfg: Optional[ArchConfig] = None
+                ) -> Tuple[ArchConfig, MultiTenantScheduler]:
+    """Model, engine and scheduler as ``args`` (from :func:`build_parser`)
+    describe them; weights are random from ``PRNGKey(0)``.  ``cfg``
+    replaces the ``--arch``/``--reduced`` lookup (a depth-cut config)."""
+    mode = serve_mode(args)
+    if cfg is None:
+        cfg = get_config(args.arch)
+        if args.reduced:
+            cfg = cfg.reduced()
     params, _ = pp.split(build_model(cfg).init(jax.random.PRNGKey(0)))
     from repro.distributed.sharding import parse_mesh, serving_sharder
     sh = serving_sharder(parse_mesh(args.mesh)) if args.mesh else None
@@ -268,18 +282,10 @@ def main(argv=None) -> int:
                 "always": "always"}[args.preserve_pristine]
     crash_kw = {}
     if args.journal_dir:
-        if mode != "continuous":
-            # only the continuous collect loop journals ROUND_COMMIT/
-            # RETIRE; a journal written under another mode would replay
-            # every completed request as pending
-            ap.error("--journal-dir requires --mode continuous")
-        import os
         crash_kw = dict(
             journal=os.path.join(args.journal_dir, "journal.jsonl"),
             checkpoint_dir=os.path.join(args.journal_dir, "checkpoints"),
             checkpoint_every=args.checkpoint_every)
-    elif args.recover or args.checkpoint_every:
-        ap.error("--recover/--checkpoint-every need --journal-dir")
     if args.crash_at_round:
         from repro.distributed.fault import FaultPlane
         crash_kw["fault_plane"] = FaultPlane(
@@ -297,6 +303,61 @@ def main(argv=None) -> int:
                         max_prompt_len=max(64, 2 * args.prompt_len
                                            + args.shared_prefix_len)),
         **crash_kw)
+    return cfg, sched
+
+
+def drain(sched: MultiTenantScheduler, late: Sequence[Request] = (),
+          stats_every: int = 0) -> List[Response]:
+    """Step the scheduler until nothing is pending.  ``late`` requests are
+    submitted after the first step (so they land against full slots);
+    ``stats_every`` prints a telemetry line every that many steps."""
+    late = list(late)
+    responses: List[Response] = []
+    steps = 0
+    while sched.pending() or late:
+        r = sched.step()
+        if r:
+            responses.extend(r)
+        steps += 1
+        if late:                 # tier-0 burst lands against full slots
+            for req in late:
+                sched.submit(req)
+            late = []
+        if stats_every and steps % stats_every == 0:
+            from repro.obs import TELEMETRY
+            from repro.obs.export import stats_line
+            print(stats_line(
+                TELEMETRY,
+                keys=("heartbeat.beats", "kv.pages_allocated",
+                      "kv.free_pages", "swap.preemptions", "swap.restores",
+                      "heartbeat.suspects"),
+                step=steps, pending=sched.pending()))
+    return responses
+
+
+def main(argv=None) -> int:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    if args.list_archs:
+        return list_archs()
+    mode = serve_mode(args)
+    if args.journal_dir and mode != "continuous":
+        # only the continuous collect loop journals ROUND_COMMIT/RETIRE; a
+        # journal written under another mode would replay every completed
+        # request as pending
+        ap.error("--journal-dir requires --mode continuous")
+    if not args.journal_dir and (args.recover or args.checkpoint_every):
+        ap.error("--recover/--checkpoint-every need --journal-dir")
+
+    from repro.obs import TELEMETRY
+    obs_on = bool(args.trace_out or args.metrics_out or args.stats_every)
+    if obs_on:
+        # light the global plane before the stack is built so every layer
+        # (engine, scheduler, pool, swap store, staging lanes) records
+        TELEMETRY.enable()
+
+    enable_compile_cache()
+    cfg, sched = build_stack(args)
 
     if args.recover:
         s = sched.recover()
@@ -344,25 +405,7 @@ def main(argv=None) -> int:
 
     # manual drain loop (same semantics as sched.drain()) so the periodic
     # stats line can fire between scheduling steps
-    responses = []
-    steps = 0
-    while sched.pending() or late:
-        r = sched.step()
-        if r:
-            responses.extend(r)
-        steps += 1
-        if late:                 # tier-0 burst lands against full slots
-            for req in late:
-                sched.submit(req)
-            late = []
-        if args.stats_every and steps % args.stats_every == 0:
-            from repro.obs.export import stats_line
-            print(stats_line(
-                TELEMETRY,
-                keys=("heartbeat.beats", "kv.pages_allocated",
-                      "kv.free_pages", "swap.preemptions", "swap.restores",
-                      "heartbeat.suspects"),
-                step=steps, pending=sched.pending()))
+    responses = drain(sched, late, args.stats_every)
     sched.close()
     n_done = sum(r.outcome == "completed" for r in responses)
     print(f"served {len(responses)} requests "

@@ -23,6 +23,7 @@ from repro.data.tokens import DataConfig, PrefetchFeed, synth_batch
 from repro.distributed import checkpoint as ckpt
 from repro.distributed.fault import HeartbeatMonitor, StragglerDetector
 from repro.distributed.sharding import Sharder, null_sharder, param_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as pp
 from repro.models.model import build_model
 from repro.training.optimizer import make_optimizer
@@ -58,6 +59,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -167,6 +167,7 @@ def apply_attention(p, x, cfg: ArchConfig, sh: Sharder, *,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     o = blockwise_attention(q, k, v, causal=causal, window=cfg.sliding_window)
+    o = sh.constrain(o, ("batch", None, "attn_out", None))
     o = o.reshape(x.shape[0], x.shape[1], -1)
     cdt = dtype_of(cfg.compute_dtype)
     out = jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(cdt))

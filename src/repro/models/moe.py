@@ -255,17 +255,16 @@ def apply_moe_ep(p, x: jax.Array, cfg: ArchConfig, sh: Sharder,
              * gates[..., None].astype(cdt)).sum(axis=1)
         return y.reshape(b_loc, s_loc, d), aux, zloss
 
-    from jax.experimental.shard_map import shard_map
     # native residual layout: batch over (pod, data), seq over model — no
     # token-flat reshard at the boundary (GSPMD falls back to
     # replicate-then-reshard on its transpose otherwise)
     blk_spec = P(dp_axes if len(dp_axes) != 1 else dp_axes[0],
                  "model" if "model" in axes else None, None)
     ew_spec = P("model", None, None) if "model" in axes else P(None, None, None)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(blk_spec, ew_spec, ew_spec, ew_spec),
-                   out_specs=(blk_spec, P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(blk_spec, ew_spec, ew_spec, ew_spec),
+                       out_specs=(blk_spec, P(), P()),
+                       check_vma=False)
     # FSDP weight all-gather (if any) happens here, outside shard_map
     wg = jax.lax.with_sharding_constraint(
         w_gate, jax.NamedSharding(mesh, ew_spec))

@@ -135,6 +135,7 @@ import numpy as np
 
 from repro.configs import ATTN, MOE, NONE, ArchConfig
 from repro.distributed.fault import InjectedFault
+from repro.kernels.ops import default_kernel_backend, resolve_interpret
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (apply_cross_attention, apply_embedding,
@@ -212,7 +213,7 @@ class ContinuousBatchingEngine:
                  batch_admission: bool = True,
                  logits_cache_size: int = 32,
                  backend: Optional[str] = None,
-                 pallas_interpret: bool = True,
+                 pallas_interpret: Optional[bool] = None,
                  swap: bool = True,
                  swap_store: Optional[HostSwapStore] = None,
                  fault_plane: Optional[Any] = None,
@@ -254,14 +255,16 @@ class ContinuousBatchingEngine:
         self.batch_admission = batch_admission
         # paged-attention backend: "jnp" gathers the dense logical window
         # per decode step (A/B baseline), "pallas" streams pages in place
-        # through the fused kernels; inherited from the engine when unset
+        # through the fused kernels; inherited from the engine when unset,
+        # else the platform's (pallas compiled on a TPU, jnp elsewhere)
         if backend is None:
-            backend = getattr(engine, "kernel_backend", "jnp")
+            backend = (getattr(engine, "kernel_backend", None)
+                       or default_kernel_backend())
         if backend not in BACKENDS:
             raise ValueError(f"backend {backend!r}: must be one of "
                              f"{BACKENDS}")
         self.backend = backend
-        self.pallas_interpret = pallas_interpret
+        self.pallas_interpret = resolve_interpret(pallas_interpret)
         # skip-prefill full hits need every per-slot state to be
         # reconstructable from shared pages + cached logits alone: cross
         # pages are per-request and SSM states are neither paged nor

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.configs import ArchConfig
 from repro.distributed.sharding import Sharder, null_sharder
+from repro.kernels.ops import default_kernel_backend
 from repro.models.model import ModelBundle, build_model
 from repro.obs.telemetry import Telemetry, get_telemetry
 
@@ -137,7 +138,7 @@ class PendingGeneration:
 class ServingEngine:
     def __init__(self, cfg: ArchConfig, params: Any,
                  sh: Optional[Sharder] = None, temperature: float = 0.0,
-                 kernel_backend: str = "jnp",
+                 kernel_backend: Optional[str] = None,
                  telemetry: Optional[Telemetry] = None):
         self.cfg = cfg
         self.tel = get_telemetry(telemetry)
@@ -153,9 +154,10 @@ class ServingEngine:
         self.temperature = temperature
         # default paged-attention backend for serving layers built on this
         # engine ("jnp" dense gather | "pallas" fused page-streaming
-        # kernels); the engine's own dense ring-cache paths are unaffected,
-        # but ContinuousBatchingEngine inherits this unless overridden
-        self.kernel_backend = kernel_backend
+        # kernels; unset = the platform's, pallas on a TPU); the engine's
+        # own dense ring-cache paths are unaffected, but
+        # ContinuousBatchingEngine inherits this unless overridden
+        self.kernel_backend = kernel_backend or default_kernel_backend()
         self.prefill_traces = 0     # compiles (one per (batch, seq) shape)
         self.prefill_calls = 0      # host invocations
 

@@ -795,7 +795,7 @@ def paged_attend(q: jax.Array, pool: Dict[str, jax.Array],
                  page_table: jax.Array, positions: jax.Array,
                  cfg: ArchConfig, *, kpos: Optional[jax.Array] = None,
                  pos_pool: Optional[jax.Array] = None,
-                 backend: str = "jnp", interpret: bool = True,
+                 backend: str = "jnp", interpret: Optional[bool] = None,
                  sh: Optional[Sharder] = None) -> jax.Array:
     """Paged attention read, backend-switched.
 
@@ -832,7 +832,7 @@ def paged_attend(q: jax.Array, pool: Dict[str, jax.Array],
 
 
 def paged_scatter(pool: jax.Array, pages: jax.Array, values: jax.Array, *,
-                  backend: str = "jnp", interpret: bool = True,
+                  backend: str = "jnp", interpret: Optional[bool] = None,
                   sh: Optional[Sharder] = None) -> jax.Array:
     """Admission-time KV scatter, backend-switched: write ``values``
     (S, nb, P, Hkv, D) into ``pool`` (S, NP, P, Hkv, D) at ``pages`` (nb,).
@@ -858,7 +858,8 @@ def paged_attention_decode(p, x, pool: Dict[str, jax.Array],
                            positions: jax.Array, cfg: ArchConfig,
                            sh: Sharder, *,
                            pos_pool: Optional[jax.Array] = None,
-                           backend: str = "jnp", interpret: bool = True):
+                           backend: str = "jnp",
+                           interpret: Optional[bool] = None):
     """Single-token GQA decode against a paged cache (per-row positions).
 
     Mirrors :func:`repro.models.layers.apply_attention_decode` operation for

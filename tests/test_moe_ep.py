@@ -17,7 +17,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models.moe import apply_moe_scatter, apply_moe_ep, init_moe
     from repro.models import params as pp
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh = Sharder(mesh, fsdp=False, seq_shard=False)
     cfg = ArchConfig(name="t", family="moe", num_layers=2, d_model=32,
         num_heads=2, num_kv_heads=2, head_dim=16, d_ff=0, vocab_size=128,

@@ -32,7 +32,8 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.training.optimizer import make_optimizer
     from repro.training.train_loop import build_train_step, init_train_state
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh = Sharder(mesh, fsdp=True, seq_shard=False)
 
     out = {}

@@ -1,0 +1,115 @@
+"""The main path's Pallas kernels compile for a described TPU v5e chip at the
+widths they run at: the paged decode read and the admission scatter at
+internlm2-1.8b widths, the aggregate-loss kernel at the paper's risk widths
+(1000 events per trial, 15 ELTs, a 2,000,000-event catalog) and the SSD scan
+at mamba2-2.7b widths.
+
+Nothing runs: the TPU compiler checks tiling, VMEM use and lowering, which
+interpret mode on the CPU cannot.  The topology is described inside a
+fixture, so only the test process that runs these tests loads the TPU
+library, and the tests skip where it cannot be described.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.configs.risk_app import CONFIG as RISK
+from repro.kernels import aggregate_loss as agg
+from repro.kernels import paged_attention as pa
+from repro.kernels import ssd_scan
+
+ARCH = get_config("internlm2-1.8b")
+SSM_ARCH = get_config("mamba2-2.7b")
+CAPACITY, PAGE, MAX_PROMPT = 8, 16, 1024
+PAGES = 2 + CAPACITY * (MAX_PROMPT // PAGE)      # reserved + one ring a slot
+RISK_TRIALS = 65_536                             # one tenant's chunk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_paged_decode_compiles(one_chip, no_compile_cache):
+    H, Hkv, D = ARCH.num_heads, ARCH.num_kv_heads, ARCH.head_dim
+    bf16 = jnp.bfloat16
+    _compile(lambda *a: pa.paged_attention_decode_pallas(*a, interpret=False),
+             one_chip,
+             ((CAPACITY, H, D), bf16), ((PAGES, PAGE, Hkv, D), bf16),
+             ((PAGES, PAGE, Hkv, D), bf16), ((PAGES, PAGE), jnp.int32),
+             ((CAPACITY, MAX_PROMPT // PAGE), jnp.int32),
+             ((CAPACITY,), jnp.int32))
+
+
+def test_paged_scatter_compiles(one_chip, no_compile_cache):
+    L, Hkv, D = ARCH.num_layers, ARCH.num_kv_heads, ARCH.head_dim
+    nb = MAX_PROMPT // PAGE
+    _compile(lambda *a: pa.paged_prefill_scatter_pallas(*a, interpret=False),
+             one_chip,
+             ((L, PAGES, PAGE, Hkv, D), jnp.bfloat16), ((nb,), jnp.int32),
+             ((L, nb, PAGE, Hkv, D), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("variant", agg.VARIANTS)
+def test_aggregate_loss_compiles(one_chip, no_compile_cache, variant):
+    K = -(-RISK.events_per_trial // RISK.chunk_events) * RISK.chunk_events
+    M = RISK.num_elts
+    compiled = _compile(
+        lambda *a: agg.aggregate_loss_pallas(*a, chunk=RISK.chunk_events,
+                                             interpret=False,
+                                             variant=variant),
+        one_chip,
+        ((RISK_TRIALS, K), jnp.int32), ((RISK.event_catalog + 1, M),
+                                        jnp.float32),
+        ((M,), jnp.float32), ((M,), jnp.float32), ((), jnp.float32),
+        ((), jnp.float32))
+    # the whole program, lane-padded table copy included, fits one chip
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_ssd_scan_compiles(one_chip, no_compile_cache):
+    ssm = SSM_ARCH.ssm
+    H = SSM_ARCH.d_model * ssm.expand // ssm.head_dim
+    L, P, N = 2 * ssm.chunk_size, ssm.head_dim, ssm.d_state
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    _compile(lambda *a: ssd_scan.ssd_chunked_pallas(
+                 *a, chunk=ssm.chunk_size, interpret=False),
+             one_chip,
+             ((1, L, H, P), bf16), ((1, L, H), f32), ((1, L, H), f32),
+             ((1, L, H, N), bf16), ((1, L, H, N), bf16))
