@@ -5,21 +5,10 @@
 Prints the execution-time / energy / EDP surfaces over (#pdev x tenants) for
 QDR and FDR InfiniBand with the paper's Table II constants, marks the paper's
 reported optima, then re-targets the model to the TPU-v5e staging profile.
-
-Closes with the telemetry-driven path: a few deployments are replayed onto
-a telemetry plane as spans (the stand-in for a profiled production run),
-`plan_from_telemetry` fits `PerfModelInputs`/`PowerParams` back out of the
-spans by least squares and re-plans — recovering the same optimum the
-static Table II constants give, which is the falsifiable check that the
-observability layer carries enough signal to drive capacity planning.
 """
 from repro.core import energymodel as em
 from repro.core import perfmodel as pm
-from repro.core.planner import full_surface, plan, plan_from_telemetry
-from repro.core.simulator import SimInputs
-from repro.core.tenancy import TenancyConfig
-from repro.obs.fit import replay_sim_run
-from repro.obs.telemetry import Telemetry
+from repro.core.planner import full_surface, plan
 
 
 def surface_text(m, pw, max_p=12, max_t=6):
@@ -60,39 +49,6 @@ def main():
     print(f"v5e: time-opt {t.n_pdev} chips x {t.tenants_per_pdev} tenants "
           f"-> {t.exec_time_s * 1e3:.0f} ms "
           f"(risk analysis becomes real-time at pod scale)")
-
-    telemetry_replan_demo()
-
-
-def telemetry_replan_demo():
-    """Fit the model back out of span telemetry and re-plan (obs/fit.py)."""
-    print("\n=== plan from telemetry (FDR, fitted from replayed spans) ===")
-    m = pm.PerfModelInputs(net=pm.FDR)
-    tel = Telemetry(enabled=True)
-    # replay a small deployment sweep onto the plane — the stand-in for a
-    # profiled production run (live serving spans work the same way)
-    for nv in (1, 2, 4, 8, 16):
-        si = SimInputs(TenancyConfig(1, nv, "sequential"), net=m.net,
-                       compute_time_1pdev=m.compute_time_1pdev,
-                       yet_mb=m.yet_mb, elt_mb=m.elt_mb, pf_mb=m.pf_mb,
-                       power=em.K20)
-        replay_sim_run(tel, si, pw=em.K20)
-    tp = plan_from_telemetry(tel)
-    st = plan(m, "time")
-    d = tp.deployment
-    print(f"fitted:  t_4gb={tp.m.net.t_4gb:.4f}s "
-          f"overhead={tp.m.net.per_vdev_overhead:.5f}s "
-          f"c1={tp.m.compute_time_1pdev:.3f}s "
-          f"p_busy={tp.pw.p_busy:.1f}W p_idle={tp.pw.p_idle_assigned:.1f}W")
-    print(f"         residuals: transfer_rms={tp.transfer_rms_s:.2e}s "
-          f"compute_rms={tp.compute_rms_s:.2e}s")
-    print(f"plan:    telemetry -> {d.n_pdev}x{d.tenants_per_pdev} "
-          f"({tp.transfer_mode}, {d.exec_time_s:.3f}s)   "
-          f"static Table II -> {st.n_pdev}x{st.tenants_per_pdev} "
-          f"({st.exec_time_s:.3f}s)")
-    agree = (d.n_pdev, d.tenants_per_pdev) == (st.n_pdev,
-                                               st.tenants_per_pdev)
-    print(f"         optima {'agree' if agree else 'DISAGREE'}")
 
 
 if __name__ == "__main__":

@@ -182,12 +182,8 @@ The span tree nests ``sched.step`` > ``round.dispatch`` > ``round.jit``
 with retrospective ``round.device`` windows, ``kv.*`` counters for page
 alloc/share/CoW-fork/evict, ``swap.*`` spans for preemption tiering and
 ``transfer.stage`` spans per staging lane (see ``repro/obs/__init__.py``
-for the full naming scheme).  The same spans drive capacity planning:
-``repro.core.planner.plan_from_telemetry`` least-squares-fits the paper's
-perf/energy model from them and re-plans (#pdev, tenancy, transfer mode)
-— ``examples/deployment_planner.py`` closes with that loop.  On the
-launch driver the equivalent knobs are ``--trace-out`` /
-``--metrics-out`` / ``--stats-every N``.
+for the full naming scheme).  On the launch driver the equivalent knobs
+are ``--trace-out`` / ``--metrics-out`` / ``--stats-every N``.
 """
 import jax
 import numpy as np

@@ -62,6 +62,7 @@ import jax
 
 from repro.core.tenancy import TenantTask, VirtualDevicePool
 from repro.core.transfer import StagingEngine, reorder_for_stragglers
+from repro.obs.telemetry import Telemetry, get_telemetry
 
 StageFn = Callable[[TenantTask], Any]           # task -> host pytree
 ComputeFn = Callable[[TenantTask, Any], Any]    # (task, device pytree) -> out
@@ -187,14 +188,18 @@ class PipelineExecutor:
     The executor owns a :class:`StagingEngine` (for placement + the staging
     log) but drives its non-blocking ``put``/``wait`` primitives instead of
     the stage-everything entry point, interleaving compute dispatch with the
-    transfer chain.
+    transfer chain.  Spans: ``pipeline.start`` around starting the waiter
+    threads, ``pipeline.dispatch`` around each compute call,
+    ``pipeline.drain`` while the host waits for the last outputs.
     """
 
-    def __init__(self, pool: VirtualDevicePool, mode: Optional[str] = None):
+    def __init__(self, pool: VirtualDevicePool, mode: Optional[str] = None,
+                 telemetry: Optional[Telemetry] = None):
         self.pool = pool
         self.mode = mode or pool.cfg.transfer_mode
         assert self.mode in ("sequential", "concurrent")
-        self.engine = StagingEngine(pool, self.mode)
+        self.tel = get_telemetry(telemetry)
+        self.engine = StagingEngine(pool, self.mode, telemetry=self.tel)
 
     # ------------------------------------------------------------------
     def run(self, tasks: Sequence[TenantTask], stage_fn: StageFn,
@@ -226,14 +231,16 @@ class PipelineExecutor:
         # pdev's compute_end (the per-tenant times feed the
         # StragglerDetector, so skew there would mis-steer the next run's
         # staging order).
-        waiters: Dict[int, CompletionWaiter] = {
-            p: CompletionWaiter(now, name="pipeline-waiter")
-            for p in {t.pdev for t in order}}
+        with self.tel.span("pipeline.start"):
+            waiters: Dict[int, CompletionWaiter] = {
+                p: CompletionWaiter(now, name="pipeline-waiter")
+                for p in {t.pdev for t in order}}
 
         def dispatch(task: TenantTask, chunk) -> None:
             self.engine.wait(chunk, t0)    # overlap point: compute of already
             te = now()                     # dispatched tenants keeps running
-            out = compute_fn(task, chunk.arrays)
+            with self.tel.span("pipeline.dispatch"):
+                out = compute_fn(task, chunk.arrays)
             timeline[task.vdev] = TenantTimeline(
                 task.vdev, task.pdev, task.slot,
                 chunk.enqueue_s, te, now(), 0.0)
@@ -256,8 +263,9 @@ class PipelineExecutor:
                     dispatch(task, chunk)
         finally:
             # always drain + reap the waiters, even when staging raises
-            for w in waiters.values():
-                w.close()
+            with self.tel.span("pipeline.drain"):
+                for w in waiters.values():
+                    w.close()
         waiter_err = [e for w in waiters.values() for e in w.errors]
         if waiter_err:
             raise waiter_err[0]

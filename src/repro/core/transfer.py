@@ -16,6 +16,10 @@ The engine exposes two levels of API: non-blocking :meth:`StagingEngine.put`
 :mod:`repro.core.pipeline` interleaves with compute dispatch (the paper's
 winning schedule), and the stage-everything :meth:`StagingEngine.stage`
 entry point (the pre-pipeline blocking schedule, kept for A/B benchmarks).
+
+Spans: ``transfer.put`` around enqueueing a chunk, ``transfer.wait``
+around blocking until it is device-resident; ``transfer.stage`` is the
+retrospective enqueue-to-ready window of each chunk.
 """
 from __future__ import annotations
 
@@ -66,7 +70,8 @@ class StagingEngine:
         ``jax.device_put`` returns immediately).  ``t0`` anchors the chunk's
         timestamps; without it the enqueue instant is the origin."""
         base = t0 if t0 is not None else time.perf_counter()
-        arrays = self._put(host_tree, self.pool.device_of(task.vdev))
+        with self.tel.span("transfer.put"):
+            arrays = self._put(host_tree, self.pool.device_of(task.vdev))
         return StagedChunk(task, arrays, time.perf_counter() - base,
                            base_s=base)
 
@@ -75,7 +80,8 @@ class StagingEngine:
         against the same origin ``put`` used (or an explicit ``t0``).
         While the caller blocks here, previously dispatched compute keeps
         running on its device — this is the pipeline's overlap point."""
-        jax.block_until_ready(chunk.arrays)
+        with self.tel.span("transfer.wait"):
+            jax.block_until_ready(chunk.arrays)
         base = t0 if t0 is not None else chunk.base_s
         chunk.ready_s = time.perf_counter() - base
         self.log.append({"vdev": chunk.task.vdev, "ready_s": chunk.ready_s,

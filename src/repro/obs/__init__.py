@@ -4,10 +4,11 @@ One process-global :class:`~repro.obs.telemetry.Telemetry` instance
 (``repro.obs.TELEMETRY``, disabled by default) collects everything the
 previously siloed stat surfaces recorded — ``TenantTimeline`` stamps,
 engine trace counters, ``PagedKVCache`` page accounting, swap-store and
-staging-lane logs, fault injections and heartbeat verdicts — as one
-falsifiable schema that `obs.export` can dump (Chrome-trace/Perfetto
-JSON, Prometheus text) and `obs.fit` can consume (least-squares fits of
-``PerfModelInputs``/``PowerParams`` for ``planner.plan_from_telemetry``).
+staging-lane logs, fault injections and heartbeat verdicts, and the
+risk pipeline's stages — as one falsifiable schema that `obs.export` can
+dump (Chrome-trace/Perfetto JSON, Prometheus text).  Live spans are also
+``jax.profiler.TraceAnnotation`` spans, so a profiler session (the
+benchmark's ``--trace 1``) holds them on the device trace's clock.
 
 Naming scheme
 =============
@@ -25,14 +26,15 @@ prefix      layer
 ``engine``  `serving.engine` — blocking/dispatch prefill + decode
 ``kv``      `serving.kvcache` — paged-pool page accounting
 ``swap``    `serving.swap` — host-tier swap store, per staging lane
-``transfer`` `core.transfer` — staging-engine chunk windows
+``transfer`` `core.transfer` — staging-engine chunk windows, put/wait
+``risk``    `risk.analysis` — an analysis, its plan, tables, slices, gather
+``pipeline`` `core.pipeline` — compute dispatch, draining the waiters
+``host``    `obs.telemetry` — garbage collections (``host.gc``)
 ``fault``   `distributed.fault` — injected faults
 ``heartbeat`` `distributed.fault` — liveness verdicts
 ``shard``   `distributed.sharding` — per-mesh-shard placements
 ``trace``   jit compile (trace-time) events, any layer
 ``timeline`` ``TenantTimeline`` entries re-expressed as spans
-``replay``  `obs.fit` — replayed simulator/bench runs
-``power``   `obs.fit` — (busy_frac, watts) samples for the energy fit
 ``journal`` `serving.journal` — WAL appends/bytes (crash safety)
 ``recovery`` `serving` — checkpoint saves, journal replay, pool restore
 ========== ==========================================================
@@ -43,8 +45,9 @@ Kinds:
   clock (`time.perf_counter`), with parent/child links from a
   per-thread span stack, e.g. ``sched.step`` > ``round.dispatch`` >
   ``round.cow``.  Retrospective spans (device windows stamped by
-  handles, simulator replays) carry ``parent_id=None``.
-* **events** — zero-length spans (``fault.round``, ``power.sample``).
+  handles, ``record_timeline``) carry ``parent_id=None`` and stay in
+  the in-memory ring only: the profiler sees live spans alone.
+* **events** — zero-length spans (``fault.round``, ``kv.alloc``).
 * **counters** — monotonically increasing (``kv.pages_allocated``,
   ``trace.decode``, ``transfer.bytes``).  Unit suffixes where not
   obvious: ``*_bytes``, ``*_pages``, ``*_s``.

@@ -4,6 +4,14 @@ Design constraints (see the package docstring for the naming scheme):
 
 * one monotonic clock (`time.perf_counter`) for every span, stored
   relative to the instance's ``t0`` so exporters never see wall-clock;
+* every live span is also a ``jax.profiler.TraceAnnotation`` of the same
+  name on the same thread, so a profiler session holds the program's
+  spans on the device trace's clock; outside a session an annotation
+  records nothing.  Retrospective spans (``record_span``,
+  ``record_timeline``) stay in the ring only;
+* ``enable()`` hooks the garbage collector (``gc.callbacks``): each
+  collection is a live ``host.gc`` span, attr ``generation``, and
+  ``disable()`` removes the hook;
 * parent/child links from a per-thread open-span stack, so nested
   ``with tel.span(...)`` blocks reconstruct as a tree;
 * a bounded, thread-safe ring buffer of closed spans (oldest dropped,
@@ -22,10 +30,13 @@ whole stack and the default costs nothing.
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -80,10 +91,11 @@ NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """Context manager for an open span on an enabled plane."""
+    """Context manager for an open span on an enabled plane, mirrored
+    into the profiler by a ``TraceAnnotation`` of the same name."""
 
     __slots__ = ("_tel", "name", "attrs", "span_id", "parent_id",
-                 "_t_start")
+                 "_t_start", "_annotation")
 
     def __init__(self, tel: "Telemetry", name: str,
                  attrs: Dict[str, Any]):
@@ -93,6 +105,7 @@ class _LiveSpan:
         self.span_id = tel._new_id()
         self.parent_id: Optional[int] = None
         self._t_start = 0.0
+        self._annotation = TraceAnnotation(name)
 
     def note(self, **attrs) -> None:
         """Attach attrs discovered mid-span (e.g. steps after collect)."""
@@ -102,11 +115,13 @@ class _LiveSpan:
         stack = self._tel._stack()
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        self._annotation.__enter__()
         self._t_start = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t_end = time.perf_counter()
+        self._annotation.__exit__(None, None, None)
         tel = self._tel
         stack = tel._stack()
         if stack and stack[-1] is self:
@@ -123,7 +138,9 @@ class Telemetry:
     """Thread-safe registry of counters, gauges, histograms and spans."""
 
     def __init__(self, enabled: bool = False, max_spans: int = 65536):
-        self._lock = threading.Lock()
+        # re-entrant: a collection can start, and open a ``host.gc`` span,
+        # while this thread holds the lock and allocates
+        self._lock = threading.RLock()
         self.enabled = enabled
         self.t0 = time.perf_counter()
         self.max_spans = max_spans
@@ -136,19 +153,37 @@ class Telemetry:
         self._next_id = 0
         self.spans_opened = 0
         self.spans_dropped = 0
+        self._gc_span: Optional[_LiveSpan] = None
 
     # -- lifecycle ----------------------------------------------------
     def enable(self, max_spans: Optional[int] = None) -> "Telemetry":
+        """Turn the plane on, and hook the collector until ``disable()``
+        (a plane constructed enabled records no ``host.gc`` spans)."""
         if max_spans is not None and max_spans != self.max_spans:
             self.max_spans = max_spans
             with self._lock:
                 self._spans = deque(self._spans, maxlen=max_spans)
         self.enabled = True
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
         return self
 
     def disable(self) -> "Telemetry":
         self.enabled = False
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
         return self
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` hook: a collection runs on one thread between
+        its ``start`` and ``stop`` calls, and no two overlap."""
+        if phase == "start":
+            self._gc_span = _LiveSpan(self, "host.gc",
+                                      {"generation": info["generation"]})
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            span, self._gc_span = self._gc_span, None
+            span.__exit__(None, None, None)
 
     def reset(self) -> "Telemetry":
         """Clear all recorded state (keeps the enabled flag and clock)."""
@@ -231,9 +266,9 @@ class Telemetry:
                     **attrs) -> Optional[int]:
         """Record a retrospective span from absolute perf_counter times.
 
-        Used for device-side windows stamped by round handles and for
-        simulator replays; returns the new span_id (for explicit
-        parent linking) or None when disabled.
+        Used for device-side windows stamped by round handles; returns
+        the new span_id (for explicit parent linking) or None when
+        disabled.  It goes to the ring only, not to the profiler.
         """
         if not self.enabled:
             return None

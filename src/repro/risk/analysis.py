@@ -26,6 +26,13 @@ Hot-path overhead control (all observable, asserted in tests/test_pipeline.py):
   (serving bursts, ``examples/risk_realtime.py``) stop re-staging ~120 MB per
   step; ``table_uploads`` counts actual uploads.  Layer aggregate terms stay
   dynamic scalars — what-if pricing perturbs them without touching the cache.
+
+Spans (``repro.obs``; one attribute check each on a disabled plane): an
+analysis is ``risk.analysis`` > ``risk.plan``, ``risk.tables``, then the
+executor's ``pipeline.start``, ``risk.slice`` / ``transfer.put`` /
+``transfer.wait`` / ``pipeline.dispatch`` per tenant and
+``pipeline.drain``, then ``risk.gather`` (YLT reassembly, which reads the
+results back).
 """
 from __future__ import annotations
 
@@ -43,6 +50,7 @@ from repro.core.pipeline import PipelineExecutor, TenantTimeline
 from repro.core.tenancy import TenancyConfig, TenantTask, VirtualDevicePool
 from repro.core.transfer import StagingEngine, reorder_for_stragglers
 from repro.kernels import ops as kops
+from repro.obs.telemetry import Telemetry, get_telemetry
 from repro.risk.tables import RiskTables
 
 # resident per-pdev table sets kept per engine (LRU on table identity)
@@ -67,8 +75,10 @@ def _loss_args(tables: RiskTables):
 class AggregateRiskAnalysis:
     def __init__(self, cfg: RiskAppConfig,
                  tenancy: Optional[TenancyConfig] = None,
-                 devices: Optional[list] = None):
+                 devices: Optional[list] = None,
+                 telemetry: Optional[Telemetry] = None):
         self.cfg = cfg
+        self.tel = get_telemetry(telemetry)
         self.tenancy = tenancy or TenancyConfig(
             n_pdev=max(1, len(devices or jax.devices())),
             tenants_per_pdev=cfg.tenants_per_device,
@@ -178,50 +188,58 @@ class AggregateRiskAnalysis:
         (stage *all* tenants, then dispatch compute), kept only so the
         benchmark harness can measure what the overlap buys.
         """
-        t_start = time.perf_counter()
-        tasks = self.pool.plan(tables.num_trials, uniform=True)
-        resident = self._resident_tables(tables)
-        agg_ret = np.float32(tables.agg_ret)
-        agg_lim = np.float32(tables.agg_lim)
-        chunk = min(self.cfg.chunk_events, tables.yet.shape[1])
+        tel = self.tel
+        with tel.span("risk.analysis"):
+            t_start = time.perf_counter()
+            with tel.span("risk.plan"):
+                tasks = self.pool.plan(tables.num_trials, uniform=True)
+            with tel.span("risk.tables"):
+                resident = self._resident_tables(tables)
+            agg_ret = np.float32(tables.agg_ret)
+            agg_lim = np.float32(tables.agg_lim)
+            chunk = min(self.cfg.chunk_events, tables.yet.shape[1])
 
-        def stage_fn(t: TenantTask):
-            sl = tables.yet[t.start:t.stop]
-            if t.pad:                 # neutral rows: pad event id 0 -> loss 0
-                sl = np.concatenate(
-                    [sl, np.zeros((t.pad, sl.shape[1]), sl.dtype)])
-            return {"yet": sl}
+            def stage_fn(t: TenantTask):
+                with tel.span("risk.slice"):
+                    sl = tables.yet[t.start:t.stop]
+                    if t.pad:     # neutral rows: pad event id 0 -> loss 0
+                        sl = np.concatenate(
+                            [sl, np.zeros((t.pad, sl.shape[1]), sl.dtype)])
+                return {"yet": sl}
 
-        def compute_fn(t: TenantTask, arrays):
-            elt, occ_ret, occ_lim = resident[t.pdev]
-            return self._step(arrays["yet"], elt, occ_ret, occ_lim,
-                              agg_ret, agg_lim, chunk=chunk)
+            def compute_fn(t: TenantTask, arrays):
+                elt, occ_ret, occ_lim = resident[t.pdev]
+                return self._step(arrays["yet"], elt, occ_ret, occ_lim,
+                                  agg_ret, agg_lim, chunk=chunk)
 
-        ylt = np.zeros(tables.num_trials, np.float32)
-        if overlapped:
-            ex = PipelineExecutor(self.pool)
-            rep = ex.run(tasks, stage_fn, compute_fn, straggler_hist)
-            for t in tasks:
-                ylt[t.start:t.stop] = np.asarray(rep.results[t.vdev])[:t.size]
-            return RunReport(ylt, time.perf_counter() - t_start,
-                             rep.per_tenant_s(), ex.engine.log, rep.timeline)
+            ylt = np.zeros(tables.num_trials, np.float32)
+            if overlapped:
+                ex = PipelineExecutor(self.pool, telemetry=tel)
+                rep = ex.run(tasks, stage_fn, compute_fn, straggler_hist)
+                with tel.span("risk.gather"):
+                    for t in tasks:
+                        ylt[t.start:t.stop] = \
+                            np.asarray(rep.results[t.vdev])[:t.size]
+                return RunReport(ylt, time.perf_counter() - t_start,
+                                 rep.per_tenant_s(), ex.engine.log,
+                                 rep.timeline)
 
-        # legacy blocking path: stage everything, then compute
-        order = reorder_for_stragglers(tasks, straggler_hist)
-        engine = StagingEngine(self.pool)
-        staged = engine.stage(order, stage_fn, block=True)
-        per_tenant: Dict[int, float] = {}
-        results = []
-        for sc in staged:             # dispatch all (async) — pdevs serialise
-            t0 = time.perf_counter()
-            out = compute_fn(sc.task, sc.arrays)
-            results.append((sc.task, out, t0))
-        for task, out, t0 in results:
-            out.block_until_ready()
-            per_tenant[task.vdev] = time.perf_counter() - t0
-            ylt[task.start:task.stop] = np.asarray(out)[:task.size]
-        return RunReport(ylt, time.perf_counter() - t_start, per_tenant,
-                         engine.log)
+            # legacy blocking path: stage everything, then compute
+            order = reorder_for_stragglers(tasks, straggler_hist)
+            engine = StagingEngine(self.pool, telemetry=tel)
+            staged = engine.stage(order, stage_fn, block=True)
+            per_tenant: Dict[int, float] = {}
+            results = []
+            for sc in staged:     # dispatch all (async) — pdevs serialise
+                t0 = time.perf_counter()
+                out = compute_fn(sc.task, sc.arrays)
+                results.append((sc.task, out, t0))
+            for task, out, t0 in results:
+                out.block_until_ready()
+                per_tenant[task.vdev] = time.perf_counter() - t0
+                ylt[task.start:task.stop] = np.asarray(out)[:task.size]
+            return RunReport(ylt, time.perf_counter() - t_start, per_tenant,
+                             engine.log)
 
     # ------------------------------------------------------------------
     def make_sharded_step(self, mesh, chunk: Optional[int] = None):

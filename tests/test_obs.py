@@ -1,5 +1,5 @@
 """Observability plane: spans/metrics core, instrumentation contracts,
-exporters, and the telemetry-driven capacity planner.
+exporters, and the plane's spans on the profiler's clock.
 
 * telemetry core: span nesting/parent links from the per-thread stack,
   zero-length events, the bounded ring (drops counted, never grown),
@@ -15,10 +15,13 @@ exporters, and the telemetry-driven capacity planner.
   (round.jit > round.dispatch > sched.step) and the Prometheus text
   exposition parses back to the counter table; a golden-file run pins
   the engine-level counter/span-name schema;
-* fit + plan: `plan_from_telemetry` on a replayed deployment sweep picks
-  the same (n_pdev, tenancy, transfer-mode) optimum as the static
-  Table II planner, with fitted predictions agreeing with the simulator.
+* profiler: a live span is a ``TraceAnnotation`` inside its parent's,
+  collections are ``host.gc`` spans only while ``enable()`` holds, and
+  the risk pipeline's spans nest under ``risk.analysis`` without moving
+  its numbers or its compile count.
 """
+import gc
+import glob
 import json
 import os
 
@@ -27,21 +30,18 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import energymodel as em
-from repro.core import perfmodel as pm
+from repro.configs.risk_app import RiskAppConfig
 from repro.core.pipeline import TenantTimeline
-from repro.core.planner import plan, plan_from_telemetry
-from repro.core.simulator import SimInputs, simulate
 from repro.core.tenancy import TenancyConfig
 from repro.models import params as pp
 from repro.models.model import build_model
 from repro.obs.export import (chrome_trace, parse_prometheus_text,
                               prometheus_text, stats_line,
                               write_chrome_trace)
-from repro.obs.fit import (fit_perf_inputs, fit_power_params, PhaseSample,
-                           replay_sim_run, samples_from_telemetry)
 from repro.obs.telemetry import (NULL_SPAN, record_timeline, Telemetry,
                                  TELEMETRY)
+from repro.risk.analysis import AggregateRiskAnalysis
+from repro.risk.tables import generate
 from repro.serving.continuous import ContinuousBatchingEngine
 from repro.serving.engine import ServingEngine
 from repro.serving.multitenant import MultiTenantScheduler, Request
@@ -441,69 +441,121 @@ def test_golden_counters_and_span_names(engine, rng):
 
 
 # ---------------------------------------------------------------------
-# fit + plan acceptance
+# the plane on the profiler's clock, and the risk pipeline's spans
 # ---------------------------------------------------------------------
-def _fdr_sweep(tel, nvs=(1, 2, 4, 8, 16)):
-    m = pm.PerfModelInputs(net=pm.FDR)
-    for nv in nvs:
-        si = SimInputs(TenancyConfig(1, nv, "sequential"), net=m.net,
-                       compute_time_1pdev=m.compute_time_1pdev,
-                       yet_mb=m.yet_mb, elt_mb=m.elt_mb, pf_mb=m.pf_mb,
-                       power=em.K20)
-        replay_sim_run(tel, si, pw=em.K20)
-    return m
+RISK_SPANS = ("risk.plan", "risk.tables", "pipeline.start", "risk.slice",
+              "transfer.put", "transfer.wait", "pipeline.dispatch",
+              "pipeline.drain", "risk.gather")
 
 
-def test_plan_from_telemetry_matches_static_planner():
-    """ISSUE acceptance: replay a deployment sweep, fit, re-plan — the
-    telemetry plan picks the paper's FDR optimum (9x2, sequential) and
-    the fitted model's predictions agree with the simulator."""
+@pytest.fixture(scope="module")
+def risk_setup():
+    cfg = RiskAppConfig().reduced()
+    return cfg, generate(cfg, seed=0)
+
+
+def test_live_span_lands_in_the_profiler_trace(tmp_path):
+    """An enabled plane's span is a profiler annotation of the same name,
+    on the same host line and inside its parent's."""
+    from jax.profiler import ProfileData
     tel = Telemetry(enabled=True)
-    m = _fdr_sweep(tel)
-    tp = plan_from_telemetry(tel)
-    st = plan(m, "time")
-    d = tp.deployment
-    assert (d.n_pdev, d.tenants_per_pdev) == (st.n_pdev,
-                                              st.tenants_per_pdev) == (9, 2)
-    assert tp.transfer_mode == "sequential"          # the paper's winner
-    # the replay is exactly model-generated, so the fit recovers the
-    # Table II constants to fp precision and residuals are numerical dust
-    assert tp.m.net.t_4gb == pytest.approx(pm.FDR.t_4gb, rel=1e-6)
-    assert tp.m.compute_time_1pdev == pytest.approx(
-        pm.COMPUTATION_TIME_1PDEV, rel=1e-6)
-    assert tp.pw.p_busy == pytest.approx(em.K20.p_busy, rel=1e-6)
-    assert tp.pw.p_idle_assigned == pytest.approx(em.K20.p_idle_assigned,
-                                                  rel=1e-6)
-    assert tp.transfer_rms_s < 1e-9 and tp.compute_rms_s < 1e-9
-    # fitted model vs simulator at the chosen deployment: same makespan
-    si = SimInputs(TenancyConfig(d.n_pdev, d.tenants_per_pdev,
-                                 "sequential"), net=tp.m.net,
-                   compute_time_1pdev=tp.m.compute_time_1pdev,
-                   yet_mb=tp.m.yet_mb, elt_mb=tp.m.elt_mb,
-                   pf_mb=tp.m.pf_mb, power=tp.pw)
-    assert pm.exec_time_multitenancy(
-        d.n_pdev, d.tenants_per_pdev, tp.m) == pytest.approx(
-        simulate(si).makespan, rel=1e-6)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tel.span("risk.analysis"):
+            with tel.span("risk.slice"):
+                jax.numpy.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("risk.analysis", "risk.slice"):
+                    found[e.name] = (plane.name, line.name, e.start_ns,
+                                     e.start_ns + e.duration_ns)
+    outer, inner = found["risk.analysis"], found["risk.slice"]
+    assert outer[:2] == inner[:2]
+    assert outer[2] <= inner[2] <= inner[3] <= outer[3]
+    # the ring holds the same two spans, the child linked to its parent
+    spans = {s.name: s for s in tel.spans()}
+    assert spans["risk.slice"].parent_id == spans["risk.analysis"].span_id
 
 
-def test_samples_pair_transfer_with_compute():
+def test_collections_are_spans_only_while_enabled():
+    """``enable()`` hooks the collector and ``disable()`` unhooks it; a
+    plane constructed enabled, as the tests' planes are, never hooks."""
+    assert not any(isinstance(getattr(cb, "__self__", None), Telemetry)
+                   for cb in gc.callbacks)
+    built_on = Telemetry(enabled=True)
+    gc.collect()
+    assert built_on._on_gc not in gc.callbacks
+    assert built_on.spans(name="host.gc") == []
+    tel = Telemetry().enable()
+    try:
+        assert tel._on_gc in gc.callbacks
+        with tel.span("risk.analysis"):
+            gc.collect()
+    finally:
+        tel.disable()
+    assert tel._on_gc not in gc.callbacks
+    colls = tel.spans(name="host.gc")
+    outer, = tel.spans(name="risk.analysis")
+    full = [c for c in colls if c.attrs == {"generation": 2}]
+    assert full and all(c.parent_id == outer.span_id for c in full)
+    gc.collect()
+    assert len(tel.spans(name="host.gc")) == len(colls)
+
+
+def test_disabled_plane_allocates_nothing_on_risk_path(risk_setup):
+    """With the global plane off, a whole analysis opens no span and
+    touches no counter: each hook is one attribute check."""
+    cfg, tables = risk_setup
+    assert not TELEMETRY.enabled
+    assert TELEMETRY._on_gc not in gc.callbacks
+    before = (TELEMETRY.spans_opened, TELEMETRY.metric_snapshot())
+    ara = AggregateRiskAnalysis(cfg, TenancyConfig(1, 2))
+    assert ara.tel is TELEMETRY
+    ara.run_tenant_chunked(tables)
+    assert (TELEMETRY.spans_opened, TELEMETRY.metric_snapshot()) == before
+
+
+@pytest.mark.parametrize("mode", ["sequential", "concurrent"])
+def test_risk_spans_nest_under_the_analysis(risk_setup, mode):
+    """An enabled plane records each stage of an analysis under
+    ``risk.analysis``: per tenant one slice, put, wait and dispatch.  The
+    YLT is bit-identical to a run with the plane off, and the step
+    compiles as often."""
+    cfg, tables = risk_setup
+    tenants = 4
+    off = AggregateRiskAnalysis(cfg, TenancyConfig(1, tenants, mode),
+                                telemetry=Telemetry())
     tel = Telemetry(enabled=True)
-    _fdr_sweep(tel, nvs=(1, 4))
-    samples = samples_from_telemetry(tel)
-    assert len(samples) == 1 + 4            # one sample per tenant event
-    assert {s.nv for s in samples} == {1, 4}
-    for s in samples:
-        assert s.transfer_s > 0 and s.compute_s > 0
-
-
-def test_fit_error_paths():
-    one_nv = [PhaseSample(2, 0.5, 1.0), PhaseSample(2, 0.5, 1.0)]
-    with pytest.raises(ValueError, match="distinct"):
-        fit_perf_inputs(one_nv)
-    with pytest.raises(ValueError, match=">= 2"):
-        fit_power_params([(0.5, 80.0)])
-    with pytest.raises(ValueError, match="variation"):
-        fit_power_params([(0.5, 80.0), (0.5, 80.0)])
+    on = AggregateRiskAnalysis(cfg, TenancyConfig(1, tenants, mode),
+                               telemetry=tel)
+    want = off.run_tenant_chunked(tables).ylt
+    got = on.run_tenant_chunked(tables).ylt
+    np.testing.assert_array_equal(got, want)
+    assert on.trace_count == off.trace_count == 1
+    spans = tel.spans()
+    by_id = {s.span_id: s for s in spans}
+    root, = [s for s in spans if s.name == "risk.analysis"]
+    assert root.parent_id is None
+    counts = {}
+    for s in spans:
+        counts[s.name] = counts.get(s.name, 0) + 1
+        if s.name in RISK_SPANS:
+            up = s
+            while up.parent_id is not None:
+                up = by_id[up.parent_id]
+            assert up is root, s.name
+            assert root.t_start <= s.t_start <= s.t_end <= root.t_end
+    assert {n: counts.get(n, 0) for n in RISK_SPANS} == {
+        "risk.plan": 1, "risk.tables": 1, "pipeline.start": 1,
+        "risk.slice": tenants,
+        "transfer.put": tenants, "transfer.wait": tenants,
+        "pipeline.dispatch": tenants, "pipeline.drain": 1, "risk.gather": 1}
 
 
 # ---------------------------------------------------------------------
