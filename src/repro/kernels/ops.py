@@ -109,10 +109,11 @@ def pallas_enabled() -> bool:
 
 
 def use_aggregate_variant(name: str) -> None:
-    """Select the aggregate_loss Pallas lookup strategy: "gather" (per-event
-    row DMA) or "onehot" (gather-free one-hot x ELT matmul on the MXU).
-    Also settable via REPRO_AGG_VARIANT.  No effect on the jnp reference
-    path, which is lookup-strategy-free."""
+    """Select the aggregate_loss Pallas lookup strategy: "gather" (one
+    lookup per event, from VMEM or by XLA's gather, chosen by the catalog's
+    size: :func:`aggregate_lookup`) or "onehot" (gather-free one-hot x ELT
+    matmul on the MXU).  Also settable via REPRO_AGG_VARIANT.  No effect on
+    the jnp reference path, which is lookup-strategy-free."""
     if name not in AGG_VARIANTS:
         raise ValueError(f"variant {name!r}: must be one of {AGG_VARIANTS}")
     _STATE["agg_variant"] = name
@@ -120,6 +121,21 @@ def use_aggregate_variant(name: str) -> None:
 
 def aggregate_variant() -> str:
     return _STATE["agg_variant"]
+
+
+def aggregate_lookup(rows: int, variant: Optional[str] = None) -> str:
+    """The lookup :func:`aggregate_loss` runs for a catalog of ``rows`` ELT
+    rows, with ``variant`` as it would be given: "jnp" (the reference),
+    "onehot", or the gather variant's "vmem" (combined occurrence losses
+    resident in VMEM, where they fit its budget) or "xla" (XLA's gather of
+    them from HBM)."""
+    if not pallas_enabled():
+        return "jnp"
+    variant = variant or _STATE["agg_variant"]
+    if variant != "gather":
+        return variant
+    from repro.kernels import aggregate_loss as _agg
+    return _agg.lookup_path(rows)
 
 
 # ---------------------------------------------------------------------------
@@ -158,13 +174,16 @@ def aggregate_loss(event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,
     Pads the event axis to a chunk multiple with event id 0 — the pad event
     row of every ELT is zero by contract, so pads contribute no loss.
     ``variant`` overrides the configured Pallas lookup strategy (see
-    :func:`use_aggregate_variant`); ignored on the jnp reference path."""
+    :func:`use_aggregate_variant`); ignored on the jnp reference path.  With
+    "gather", a catalog whose combined occurrence losses fit the kernel's
+    VMEM budget (the paper's 2,000,001 rows do) is looked up in VMEM, a
+    larger one by XLA's gather; :func:`aggregate_lookup` says which runs."""
     K = event_ids.shape[1]
     chunk = min(chunk, K)
     pad = (-K) % chunk
     if pad:
         event_ids = jnp.pad(event_ids, ((0, 0), (0, pad)))
-    if pallas_enabled():
+    if aggregate_lookup(elt_losses.shape[0], variant) != "jnp":
         from repro.kernels import aggregate_loss as _agg
         return _agg.aggregate_loss_pallas(
             event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,

@@ -32,7 +32,9 @@ analysis is ``risk.analysis`` > ``risk.plan``, ``risk.tables``, then the
 executor's ``pipeline.start``, ``risk.slice`` / ``transfer.put`` /
 ``transfer.wait`` / ``pipeline.dispatch`` per tenant and
 ``pipeline.drain``, then ``risk.gather`` (YLT reassembly, which reads the
-results back).
+results back).  Each dispatched step counts ``risk.lookup.<path>``, the
+lookup its program was traced with (``kernels.ops.aggregate_lookup``:
+``vmem`` or ``xla`` on the Pallas gather).
 """
 from __future__ import annotations
 
@@ -87,6 +89,8 @@ class AggregateRiskAnalysis:
                                       devices or jax.devices())
         self._step = jax.jit(self._trial_losses, static_argnames=("chunk",))
         self.trace_count = 0          # incremented at trace time only
+        # ELT rows -> the risk.lookup.* counter of the traced step
+        self._lookup_counters: Dict[int, str] = {}
         self.table_uploads = 0        # host->device ELT/term table stagings
         # key -> (host refs pinning the key's id()s, {pdev: device arrays})
         self._table_cache: "collections.OrderedDict[Tuple, Tuple]" = \
@@ -95,7 +99,9 @@ class AggregateRiskAnalysis:
     # ------------------------------------------------------------------
     def _trial_losses(self, yet, elt, occ_ret, occ_lim, agg_ret, agg_lim,
                       chunk: int):
-        self.trace_count += 1         # side effect runs only while tracing
+        self.trace_count += 1         # side effects run only while tracing
+        self._lookup_counters[elt.shape[0]] = \
+            "risk.lookup." + kops.aggregate_lookup(elt.shape[0])
         return kops.aggregate_loss(yet, elt, occ_ret, occ_lim, agg_ret,
                                    agg_lim, chunk=chunk)
 
@@ -209,8 +215,10 @@ class AggregateRiskAnalysis:
 
             def compute_fn(t: TenantTask, arrays):
                 elt, occ_ret, occ_lim = resident[t.pdev]
-                return self._step(arrays["yet"], elt, occ_ret, occ_lim,
-                                  agg_ret, agg_lim, chunk=chunk)
+                out = self._step(arrays["yet"], elt, occ_ret, occ_lim,
+                                 agg_ret, agg_lim, chunk=chunk)
+                tel.count(self._lookup_counters[elt.shape[0]])
+                return out
 
             ylt = np.zeros(tables.num_trials, np.float32)
             if overlapped:

@@ -3,6 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import aggregate_loss as agg
 from repro.kernels.aggregate_loss import aggregate_loss_pallas
 from repro.kernels.ref import aggregate_loss_chunked_ref, aggregate_loss_ref
 
@@ -37,6 +38,85 @@ def test_pallas_matches_oracle(rng, T, K, M, cat, chunk, tb, rt, variant):
     want = aggregate_loss_chunked_ref(*args, chunk=chunk)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-3)
+
+
+# catalog + 1 rows not a multiple of 128, ids at row 0 and the last row
+EDGE = [
+    # T, K, M, cat, chunk
+    (40, 200, 15, 300, 40),
+    (9, 64, 4, 126, 64),
+    (24, 256, 7, 1000, 128),
+]
+
+
+@pytest.mark.parametrize("T,K,M,cat,chunk", EDGE)
+def test_vmem_lookup_edge_rows(rng, T, K, M, cat, chunk):
+    assert (cat + 1) % 128 and agg.lookup_path(cat + 1) == "vmem"
+    ids, *rest = _case(rng, T, K, M, cat)
+    ids = ids.at[:, 0].set(0).at[:, 1].set(cat).at[0].set(cat)
+    args = (ids, *rest[:3], np.float32(0), np.float32(1e9))
+    got = aggregate_loss_pallas(*args, chunk=chunk)
+    want = aggregate_loss_chunked_ref(*args, chunk=chunk)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-3)
+
+
+def test_xla_fallback_beyond_budget(rng, monkeypatch):
+    """A catalog whose combined losses exceed the VMEM budget is looked up
+    by XLA's gather, with the same answers."""
+    args = _case(rng, 32, 64, 5, 700)
+    want = aggregate_loss_chunked_ref(*args, chunk=32)
+    monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 4 * 700)
+    assert agg.lookup_path(701) == "xla"
+    assert agg.lookup_path(700 - 128) == "vmem"
+    got = aggregate_loss_pallas(*args, chunk=32, trial_block=8)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-3)
+
+
+def test_aggregate_lookup_follows_variant(monkeypatch):
+    """kernels.ops names the lookup aggregate_loss runs, a ``variant=``
+    override included."""
+    from repro.kernels import ops as kops
+    prev_pallas, prev_variant = kops.pallas_enabled(), kops.aggregate_variant()
+    try:
+        kops.use_pallas(False)
+        assert kops.aggregate_lookup(701, "onehot") == "jnp"
+        kops.use_pallas(True)
+        kops.use_aggregate_variant("onehot")
+        assert kops.aggregate_lookup(701) == "onehot"
+        assert kops.aggregate_lookup(701, "gather") == "vmem"
+        monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 4 * 700)
+        assert kops.aggregate_lookup(701, "gather") == "xla"
+    finally:
+        kops.use_pallas(prev_pallas)
+        kops.use_aggregate_variant(prev_variant)
+
+
+def test_tenant_steps_count_their_lookup():
+    """Each step a two-tenant analysis dispatches counts the lookup its
+    program compiled."""
+    from repro.configs.risk_app import RiskAppConfig
+    from repro.core.tenancy import TenancyConfig
+    from repro.kernels import ops as kops
+    from repro.obs.telemetry import Telemetry
+    from repro.risk.analysis import AggregateRiskAnalysis
+    from repro.risk.tables import generate
+    cfg = RiskAppConfig().reduced()
+    tables = generate(cfg, seed=0)
+    want = AggregateRiskAnalysis(cfg, TenancyConfig(1, 2)).run_single(tables)
+    tel = Telemetry(enabled=True)
+    prev = kops.pallas_enabled()
+    kops.use_pallas(True)
+    try:
+        ara = AggregateRiskAnalysis(cfg, TenancyConfig(1, 2), telemetry=tel)
+        ylt = ara.run_tenant_chunked(tables).ylt
+    finally:
+        kops.use_pallas(prev)
+    lookups = {k: v for k, v in tel.counter_snapshot().items()
+               if k.startswith("risk.lookup.")}
+    assert lookups == {"risk.lookup.vmem": 2}
+    np.testing.assert_allclose(ylt, want, rtol=1e-5, atol=1e-3)
 
 
 def test_variant_selection_via_ops(rng):

@@ -1,8 +1,9 @@
 """The main path's Pallas kernels compile for a described TPU v5e chip at the
 widths they run at: the paged decode read and the admission scatter at
 internlm2-1.8b widths, the aggregate-loss kernel at the paper's risk widths
-(1000 events per trial, 15 ELTs, a 2,000,000-event catalog) and the SSD scan
-at mamba2-2.7b widths.
+(1000 events per trial, 15 ELTs, a 2,000,000-event catalog: the VMEM lookup,
+and XLA's gather with the budget lowered) and the SSD scan at mamba2-2.7b
+widths.
 
 Nothing runs: the TPU compiler checks tiling, VMEM use and lowering, which
 interpret mode on the CPU cannot.  The topology is described inside a
@@ -58,10 +59,12 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, kernel: bool = True):
+    """Compile ``fn`` for ``sharding``'s chip; ``kernel``: whether the
+    program must hold a Pallas kernel (or must hold none)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel
     return compiled
 
 
@@ -85,22 +88,39 @@ def test_paged_scatter_compiles(one_chip, no_compile_cache):
              ((L, nb, PAGE, Hkv, D), jnp.bfloat16))
 
 
-@pytest.mark.parametrize("variant", agg.VARIANTS)
-def test_aggregate_loss_compiles(one_chip, no_compile_cache, variant):
+def _compile_aggregate_loss(sharding, variant, kernel=True):
     K = -(-RISK.events_per_trial // RISK.chunk_events) * RISK.chunk_events
     M = RISK.num_elts
     compiled = _compile(
         lambda *a: agg.aggregate_loss_pallas(*a, chunk=RISK.chunk_events,
                                              interpret=False,
                                              variant=variant),
-        one_chip,
+        sharding,
         ((RISK_TRIALS, K), jnp.int32), ((RISK.event_catalog + 1, M),
                                         jnp.float32),
         ((M,), jnp.float32), ((M,), jnp.float32), ((), jnp.float32),
-        ((), jnp.float32))
-    # the whole program, lane-padded table copy included, fits one chip
+        ((), jnp.float32), kernel=kernel)
+    # the whole program, any relaid table copy included, fits one chip
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    return mem
+
+
+@pytest.mark.parametrize("variant", agg.VARIANTS)
+def test_aggregate_loss_compiles(one_chip, no_compile_cache, variant):
+    mem = _compile_aggregate_loss(one_chip, variant)
+    if variant == "gather":
+        # the VMEM lookup: no lane-padded copy of the 120 MB ELT, only the
+        # combined occurrence losses (8 MB) and the YLT
+        assert agg.lookup_path(RISK.event_catalog + 1) == "vmem"
+        assert mem.temp_size_in_bytes < 64e6
+
+
+def test_aggregate_loss_xla_fallback_compiles(one_chip, no_compile_cache,
+                                              monkeypatch):
+    monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 1 << 20)
+    assert agg.lookup_path(RISK.event_catalog + 1) == "xla"
+    _compile_aggregate_loss(one_chip, "gather", kernel=False)
 
 
 def test_ssd_scan_compiles(one_chip, no_compile_cache):
