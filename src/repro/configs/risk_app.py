@@ -2,8 +2,11 @@
 
 Paper-scale inputs: YET = 1M trials x 1000 (event, timestamp) pairs (~4 GB
 int32 pairs when packed), 15 ELTs covered by one layer (ELT total ~120 MB),
-PF ~4 MB of financial terms.  The dry-run lowers the tenant-chunked analysis
-step over the production mesh.
+PF ~4 MB of financial terms.  ``num_layers`` > 1 prices a portfolio of
+layers over the same ELTs (a reinsurance tower, each layer its own
+occurrence and aggregate terms) in the same step, one YLT column a layer.
+The dry-run lowers the tenant-chunked analysis step over the production
+mesh.
 """
 from dataclasses import dataclass
 from typing import Tuple
@@ -17,8 +20,7 @@ class RiskAppConfig:
     events_per_trial: int = 1000
     num_elts: int = 15              # ELTs covered by the layer (3..30 per paper)
     event_catalog: int = 2_000_000  # direct-access table size per ELT
-    num_programs: int = 1
-    num_layers: int = 1
+    num_layers: int = 1             # layers priced over the ELTs (a tower)
     chunk_events: int = 128         # paper's "chunking" (shared-mem → VMEM tile)
     tenants_per_device: int = 2     # vGPUs per pGPU
     transfer_mode: str = "sequential"  # sequential | concurrent

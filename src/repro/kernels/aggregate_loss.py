@@ -5,35 +5,51 @@ per trial and reads the ELT direct-access tables from global memory with
 per-thread random access, chunking the event axis through shared memory.  A
 2,000,000-event catalog of 15 ELTs does not fit the TPU's VMEM, and Mosaic
 has no general vector gather, so the lookup strategies below keep the table
-in a form the chip can address:
+in a form the chip can address.
+
+A call prices one layer, or a portfolio of L layers over the same ELTs
+(a reinsurance tower): occurrence terms ``(M,)`` and scalar aggregate terms
+give a ``(T,)`` year-loss table (YLT), terms ``(L, M)`` and ``(L,)`` a
+``(T, L)`` one.  A layer that does not cover an ELT has a zero occurrence
+limit on it.
 
 * ``gather`` (default) — one lookup per event.  The occurrence terms are
-  per ELT and the layer adds an event's clipped ELT losses, so every call
-  first combines them per catalog row, ``f[e] = sum_m min(max(elt[e, m] -
-  OccR_m, 0), OccL_m)``, from the ELT and terms it is given (nothing
-  derived from them outlives the call), and looks each event up in ``f``
-  in one of two ways chosen by shape (:func:`lookup_path`):
+  per ELT and a layer adds an event's clipped ELT losses, so every call
+  first combines them per catalog row and layer, ``f[e, l] = sum_m
+  min(max(elt[e, m] - OccR_lm, 0), OccL_lm)``, from the ELT and terms it
+  is given (nothing derived from them outlives the call), and looks each
+  event up in ``f`` in one of two ways chosen by shape
+  (:func:`lookup_path`):
 
-  - ``vmem`` — where ``f``'s lane-dense (rows / 128, 128) float32 layout
-    fits :data:`LOOKUP_VMEM_BUDGET` (the paper's 2,000,001 rows do), it is
-    fetched into VMEM once a call, single-buffered.  Grid = (trial_blocks,
-    event_chunks), whole rows of event ids a step where they fit
-    :data:`LOOKUP_SMEM_BUDGET`; for each event the kernel reads the id from
-    SMEM, loads row ``id >> 7`` of ``f`` with a dynamic sublane load and
-    adds lane ``id & 127`` into the trial's lane accumulator (compare with
-    a lane iota, select, add).  No DMA per event.
-  - ``xla`` — for catalogs too large for that budget: XLA's gather of the
-    same ``f`` from HBM, ``f[ids].sum(-1)``, then the aggregate terms.
+  - ``vmem`` — where ``f``, laid out lane-dense, fits
+    :data:`LOOKUP_VMEM_BUDGET` (the paper's 2,000,001 rows do, for one
+    layer in 8 MB and for eight in 64 MiB), it is fetched into VMEM once a
+    call, single-buffered.  Grid = (trial_blocks, event_chunks), whole
+    rows of event ids a step where they fit :data:`LOOKUP_SMEM_BUDGET`;
+    for each event the kernel reads the id from SMEM, loads the event's
+    row of the table with one dynamic sublane load and adds the event's
+    lanes into the trial's lane accumulator (compare with a lane iota,
+    select, add).  No DMA per event.  One layer's table is (rows / 128,
+    128): row ``id >> 7``, lane ``id & 127``.  A tower's layers are padded
+    to a power of two, Lp, and interleaved so that one load brings every
+    layer of an event: a (R, 128) table, R a power of two of at least
+    rows / G with G = 128 / Lp, catalog row ``id`` at row ``id & (R -
+    1)``, lanes ``(id >> log2 R) * Lp + l`` for its layers ``l``
+    (:func:`_tower_table`; built by a 2-D transpose of the layer-major
+    combine).  The last chunk adds the accumulator's G lane groups into
+    the trial's Lp layer losses, by rolls.
+  - ``xla`` — for tables too large for that budget: XLA's gather of the
+    same ``f`` from HBM, then the aggregate terms.
 
 * ``onehot`` — gather-free: the ELT is transposed to (M, catalog) and tiled
   along the catalog in lane-aligned tiles; each trial's event ids become a
   (rows_tile, C) one-hot matrix that the MXU multiplies by the tile at
   ``Precision.HIGHEST`` (exact: one 1.0 per column).  Every event is
   compared against every catalog row, so the work grows with the catalog;
-  it suits small catalogs only.
+  it suits small catalogs only.  A tower runs it once a layer.
 
 Both kernels accumulate the YLT block across event chunks (revisiting
-output) and apply the layer aggregate terms on the last visit.  They are
+output) and apply each layer's aggregate terms on the last visit.  They are
 validated in interpret mode against
 :func:`repro.kernels.ref.aggregate_loss_chunked_ref`
 (tests/test_kernels_aggregate.py) and compiled for a described v5e at the
@@ -57,9 +73,10 @@ LANES = 128
 # ELT tile plus the (rows, C) one-hot and row-id planes it is compared with
 ONEHOT_VMEM_BUDGET = 8 << 20
 # VMEM budget for the gather variant's combined occurrence losses, one float32
-# per catalog row: within it the lookup is a VMEM load (the ``vmem`` path),
-# beyond it XLA's gather from HBM (``xla``)
-LOOKUP_VMEM_BUDGET = 16 << 20
+# per catalog row and padded layer: within it the lookup is a VMEM load (the
+# ``vmem`` path), beyond it XLA's gather from HBM (``xla``).  A v5e has
+# 128 MiB of VMEM; the call asks for the table plus 8 MiB
+LOOKUP_VMEM_BUDGET = 96 << 20
 # SMEM budget for one block of event ids on the ``vmem`` path (two are in
 # flight, and SMEM holds 1 MiB on a v5e)
 LOOKUP_SMEM_BUDGET = 256 << 10
@@ -81,22 +98,58 @@ def _aggregate_terms(out_ref, agg_ref):
                                agg_ref[1])
 
 
-def lookup_path(rows: int) -> str:
+def padded_layers(layers: int) -> int:
+    """Layers as the ``vmem`` table holds them: the next power of two."""
+    if not 1 <= layers <= LANES:
+        raise ValueError(f"{layers} layers: the lookup prices 1 to {LANES}")
+    return 1 << (layers - 1).bit_length()
+
+
+def table_rows(rows: int, layers: int = 1) -> int:
+    """Rows of 128 lanes the ``vmem`` table of a ``rows``-row catalog takes:
+    one layer packs 128 catalog rows a table row; a tower packs 128 / Lp
+    catalog rows into a power-of-two count of table rows."""
+    per_row = LANES // padded_layers(layers)
+    if layers == 1:
+        return -(-rows // per_row)
+    return 1 << (-(-rows // per_row) - 1).bit_length()
+
+
+def lookup_path(rows: int, layers: int = 1) -> str:
     """The ``gather`` variant's lookup for a catalog of ``rows`` rows (pad
-    row 0 included): ``"vmem"`` where the combined occurrence losses, laid
-    out lane-dense, fit :data:`LOOKUP_VMEM_BUDGET`, else ``"xla"``."""
-    vmem_bytes = 4 * _round_up(rows, LANES)
+    row 0 included) priced for ``layers`` layers: ``"vmem"`` where the
+    combined occurrence losses, laid out lane-dense
+    (:func:`table_rows` x 128 float32), fit :data:`LOOKUP_VMEM_BUDGET`,
+    else ``"xla"``."""
+    vmem_bytes = 4 * LANES * table_rows(rows, layers)
     return "vmem" if vmem_bytes <= LOOKUP_VMEM_BUDGET else "xla"
 
 
 def _combined_losses(elt, occ_ret, occ_lim):
-    """(rows,) occurrence losses per catalog row: per ELT, then over ELTs."""
+    """Occurrence losses per catalog row, per ELT and then over ELTs:
+    ``(rows,)`` for terms ``(M,)``; layer-major ``(L, rows)`` for terms
+    ``(L, M)``, so that the catalog stays on the lanes."""
+    if occ_ret.ndim == 2:
+        return jnp.minimum(jnp.clip(elt.T[None] - occ_ret[..., None], 0.0,
+                                    None), occ_lim[..., None]).sum(axis=1)
     return jnp.minimum(jnp.clip(elt - occ_ret[None], 0.0, None),
                        occ_lim[None]).sum(axis=1)
 
 
+def _tower_table(f, rows_out: int):
+    """The ``vmem`` table of L layers' (L, rows) combined losses: Lp =
+    :func:`padded_layers`, G = 128 / Lp catalog rows a table row, and
+    catalog row ``q * rows_out + r``'s layer ``l`` at row ``r``, lane ``q *
+    Lp + l`` (zero layers and rows pad it)."""
+    layers, rows = f.shape
+    lp = padded_layers(layers)
+    f = jnp.pad(f, ((0, lp - layers), (0, LANES // lp * rows_out - rows)))
+    f = f.reshape(lp, LANES // lp, rows_out).transpose(1, 0, 2)
+    return f.reshape(LANES, rows_out).T
+
+
 def _vmem_kernel(ids_ref, f_ref, agg_ref, out_ref, acc_ref, *,
-                 n_chunks: int, unroll: int):
+                 n_chunks: int, unroll: int, layers: int):
     j = pl.program_id(1)
     tb, c = ids_ref.shape
 
@@ -105,13 +158,21 @@ def _vmem_kernel(ids_ref, f_ref, agg_ref, out_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    if layers == 1:     # row i >> 7, lane i & 127
+        row_of = lambda i: i >> 7
+        slot_of = lambda i: i & (LANES - 1)
+    else:               # row i & (R - 1), lanes of slot i >> log2(R)
+        r_bits = f_ref.shape[0].bit_length() - 1
+        row_of = lambda i: i & (f_ref.shape[0] - 1)
+        slot_of = lambda i: i >> r_bits
+        lane = lane >> (layers.bit_length() - 1)    # the slot of each lane
 
     def trial(t, carry):
         def events(k, acc):        # Mosaic unrolls by hand only
             for u in range(unroll):
                 i = ids_ref[t, k * unroll + u]
-                row = f_ref[pl.ds(i >> 7, 1), :]        # i // LANES
-                acc = acc + jnp.where(lane == (i & (LANES - 1)), row, 0.0)
+                row = f_ref[pl.ds(row_of(i), 1), :]
+                acc = acc + jnp.where(lane == slot_of(i), row, 0.0)
             return acc
 
         acc_ref[pl.ds(t, 1), :] = jax.lax.fori_loop(
@@ -122,19 +183,38 @@ def _vmem_kernel(ids_ref, f_ref, agg_ref, out_ref, acc_ref, *,
 
     @pl.when(j == n_chunks - 1)
     def _agg():
-        out_ref[...] = acc_ref[...].sum(axis=1, keepdims=True)
-        _aggregate_terms(out_ref, agg_ref)
+        if layers == 1:
+            out_ref[...] = acc_ref[...].sum(axis=1, keepdims=True)
+            _aggregate_terms(out_ref, agg_ref)
+            return
+        # add the slots up: after rolls by 64, 32, ..., Lp (either way
+        # round) every lane holds the sum of the lanes of its layer
+        acc = acc_ref[...]
+        shift = LANES // 2
+        while shift >= layers:
+            acc = acc + pltpu.roll(acc, shift, 1)
+            shift //= 2
+        out_ref[...] = jnp.minimum(
+            jnp.clip(acc[:, :layers] - agg_ref[0:1, :], 0.0, None),
+            agg_ref[1:2, :])
 
 
 def _vmem_lookup(ids, f, agg, *, chunk: int, trial_block: Optional[int],
                  interpret: bool):
     """The ``vmem`` path of the gather variant: ``ids`` is (T, K) with K a
-    multiple of ``chunk``, ``f`` the (rows,) combined occurrence losses.
-    Returns the (T,) YLT."""
+    multiple of ``chunk``; ``f`` the (rows,) combined occurrence losses and
+    ``agg`` the (2,) aggregate terms of one layer, or ``f`` (L, rows) and
+    ``agg`` (2, L) for L layers.  Returns the (T,) or (T, L) YLT."""
     T, K = ids.shape
-    rows = f.shape[0]
-    R = -(-rows // LANES)
-    f = jnp.pad(f, (0, R * LANES - rows)).reshape(R, LANES)
+    layered = f.ndim == 2
+    layers, rows = f.shape if layered else (1, f.shape[0])
+    lp = padded_layers(layers)
+    R = table_rows(rows, layers)
+    if layered:     # pad layers price nothing and are sliced off below
+        f = _tower_table(f, R)
+        agg = jnp.pad(agg, ((0, 0), (0, lp - layers)))
+    else:
+        f = jnp.pad(f, (0, R * LANES - rows)).reshape(R, LANES)
     # whole rows of ids a step where eight trials' fit the SMEM budget
     c = K if 4 * 8 * K <= LOOKUP_SMEM_BUDGET else chunk
     tb = trial_block or max(8, min(LOOKUP_TRIAL_BLOCK,
@@ -145,26 +225,29 @@ def _vmem_lookup(ids, f, agg, *, chunk: int, trial_block: Optional[int],
         ids = jnp.pad(ids, ((0, T_pad - T), (0, 0)))
     n_chunks = K // c
     f_bytes = 4 * R * LANES
+    # one layer's terms are SMEM scalars; a tower's a (2, Lp) VMEM block
+    agg_spec = (pl.BlockSpec((2, lp), lambda i, j: (0, 0)) if layered else
+                pl.BlockSpec(memory_space=pltpu.SMEM))
     out = pl.pallas_call(
         functools.partial(_vmem_kernel, n_chunks=n_chunks,
-                          unroll=math.gcd(c, LOOKUP_UNROLL)),
+                          unroll=math.gcd(c, LOOKUP_UNROLL), layers=lp),
         grid=(T_pad // tb, n_chunks),
         in_specs=[
             pl.BlockSpec((tb, c), lambda i, j: (i, j),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((R, LANES), lambda i, j: (0, 0),
                          pipeline_mode=pl.Buffered(1)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            agg_spec,
         ],
-        out_specs=pl.BlockSpec((tb, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((T_pad, 1), jnp.float32),
+        out_specs=pl.BlockSpec((tb, lp), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((T_pad, lp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tb, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=f_bytes + (8 << 20)),
         interpret=interpret,
     )(ids, f, agg)
-    return out[:T, 0]
+    return out[:T, :layers] if layered else out[:T, 0]
 
 
 def _onehot_kernel(ids_ref, elt_ref, ret_ref, lim_ref, agg_ref, out_ref, *,
@@ -211,18 +294,36 @@ def aggregate_loss_pallas(event_ids, elt_losses, occ_ret, occ_lim, agg_ret,
                           rows_tile: Optional[int] = None,
                           interpret: Optional[bool] = None,
                           variant: str = "gather"):
-    """Drop-in equivalent of kernels.ref.aggregate_loss_chunked_ref.
+    """Drop-in equivalent of kernels.ref.aggregate_loss_chunked_ref: one
+    layer's terms, ``(M,)`` and scalars, give the ``(T,)`` YLT; L layers'
+    terms, ``(L, M)`` and ``(L,)``, the ``(T, L)`` YLT.
 
     ``variant``: "gather" (one lookup per event of the combined occurrence
-    losses: from VMEM, or by XLA's gather where the catalog is too large,
-    see :func:`lookup_path`) or "onehot" (one-hot x catalog-tile matmul on
-    the MXU; ``rows_tile`` overrides its tile, rounded up to a multiple of
-    128).  ``trial_block`` overrides the trials a grid step prices (8 on
-    the onehot path, up to :data:`LOOKUP_TRIAL_BLOCK` on the VMEM path).  ``interpret`` defaults to the platform's choice
-    (:func:`repro.kernels.ops.resolve_interpret`)."""
+    losses, every layer at once: from VMEM, or by XLA's gather where the
+    table is too large, see :func:`lookup_path`) or "onehot" (one-hot x
+    catalog-tile matmul on the MXU, a layer at a time; ``rows_tile``
+    overrides its tile, rounded up to a multiple of 128).  ``trial_block``
+    overrides the trials a grid step prices (8 on the onehot path, up to
+    :data:`LOOKUP_TRIAL_BLOCK` on the VMEM path).  ``interpret`` defaults
+    to the platform's choice (:func:`repro.kernels.ops.resolve_interpret`).
+    """
     if variant not in VARIANTS:
         raise ValueError(f"variant {variant!r}: must be one of {VARIANTS}")
     interpret = resolve_interpret(interpret)
+    if jnp.ndim(occ_ret) == 2:
+        layers = occ_ret.shape[0]
+        if jnp.shape(agg_ret) != (layers,) or jnp.shape(agg_lim) != (layers,):
+            raise ValueError(f"{layers} layers' occurrence terms need "
+                             f"({layers},) aggregate terms, not "
+                             f"{jnp.shape(agg_ret)} and {jnp.shape(agg_lim)}")
+        if layers == 1 or variant == "onehot":
+            # one layer is the one-layer program; onehot prices a layer a call
+            one = functools.partial(
+                aggregate_loss_pallas, event_ids, elt_losses, chunk=chunk,
+                trial_block=trial_block, rows_tile=rows_tile,
+                interpret=interpret, variant=variant)
+            return jnp.stack([one(occ_ret[l], occ_lim[l], agg_ret[l],
+                                  agg_lim[l]) for l in range(layers)], axis=1)
     T, K = event_ids.shape
     rows, M = elt_losses.shape
     chunk = min(chunk, K)
@@ -236,10 +337,11 @@ def aggregate_loss_pallas(event_ids, elt_losses, occ_ret, occ_lim, agg_ret,
                      jnp.asarray(agg_lim, jnp.float32)])
     if variant == "gather":
         f = _combined_losses(elt, occ_ret, occ_lim)
-        if lookup_path(rows) == "vmem":
+        layers = f.shape[0] if f.ndim == 2 else 1
+        if lookup_path(rows, layers) == "vmem":
             return _vmem_lookup(ids, f, agg, chunk=chunk,
                                 trial_block=trial_block, interpret=interpret)
-        y = f[ids].sum(axis=1)
+        y = f.T[ids].sum(axis=1)
         return jnp.minimum(jnp.clip(y - agg[0], 0.0, None), agg[1])
 
     # the (tb, 1) YLT block needs tb % 8 == 0 (or the whole axis): pad the

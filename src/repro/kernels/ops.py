@@ -123,19 +123,23 @@ def aggregate_variant() -> str:
     return _STATE["agg_variant"]
 
 
-def aggregate_lookup(rows: int, variant: Optional[str] = None) -> str:
+def aggregate_lookup(rows: int, variant: Optional[str] = None,
+                     layers: Optional[int] = None) -> str:
     """The lookup :func:`aggregate_loss` runs for a catalog of ``rows`` ELT
     rows, with ``variant`` as it would be given: "jnp" (the reference),
     "onehot", or the gather variant's "vmem" (combined occurrence losses
     resident in VMEM, where they fit its budget) or "xla" (XLA's gather of
-    them from HBM)."""
+    them from HBM).  A tower of ``layers`` layers (terms with a layer axis)
+    names its layer count too, as in "vmem.layers8"; one layer given
+    without the axis (``None``) does not."""
     if not pallas_enabled():
-        return "jnp"
-    variant = variant or _STATE["agg_variant"]
-    if variant != "gather":
-        return variant
-    from repro.kernels import aggregate_loss as _agg
-    return _agg.lookup_path(rows)
+        path = "jnp"
+    else:
+        path = variant or _STATE["agg_variant"]
+        if path == "gather":
+            from repro.kernels import aggregate_loss as _agg
+            path = _agg.lookup_path(rows, layers or 1)
+    return path if layers is None else f"{path}.layers{layers}"
 
 
 # ---------------------------------------------------------------------------
@@ -169,7 +173,9 @@ def ssd_decode_step(state, x_t, dt_t, a_t, B_t, C_t):
 
 def aggregate_loss(event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,
                    chunk: int = 128, variant: Optional[str] = None):
-    """Year-loss per trial (paper Algorithm 3).
+    """Year-loss per trial (paper Algorithm 3): ``(T,)`` for one layer's
+    terms (``occ_ret``/``occ_lim`` ``(M,)``, scalar aggregate terms),
+    ``(T, L)`` for a portfolio of L layers' (``(L, M)`` and ``(L,)``).
 
     Pads the event axis to a chunk multiple with event id 0 — the pad event
     row of every ELT is zero by contract, so pads contribute no loss.
@@ -183,7 +189,7 @@ def aggregate_loss(event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,
     pad = (-K) % chunk
     if pad:
         event_ids = jnp.pad(event_ids, ((0, 0), (0, pad)))
-    if aggregate_lookup(elt_losses.shape[0], variant) != "jnp":
+    if pallas_enabled():
         from repro.kernels import aggregate_loss as _agg
         return _agg.aggregate_loss_pallas(
             event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,

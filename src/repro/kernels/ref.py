@@ -179,6 +179,14 @@ def paged_scatter_ref(pool, pages, values) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Aggregate Risk Analysis (paper Algorithm 3) — trial-loss oracle
 # ---------------------------------------------------------------------------
+def _per_layer(fn, event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,
+               **kw):
+    """(T, L): ``fn``'s one-layer YLT for each layer's terms."""
+    return jnp.stack([fn(event_ids, elt_losses, occ_ret[l], occ_lim[l],
+                         agg_ret[l], agg_lim[l], **kw)
+                      for l in range(occ_ret.shape[0])], axis=1)
+
+
 def aggregate_loss_ref(event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim):
     """Year-loss for each trial (paper Algorithm 3), pure jnp.
 
@@ -189,10 +197,16 @@ def aggregate_loss_ref(event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim
     agg_ret/agg_lim : ()  f32  — layer aggregate terms T
     Returns yl: (T,) f32 — the Year Loss Table.
 
+    A portfolio of L layers over the same ELTs gives its terms per layer,
+    occ (L, M) and agg (L,), and gets a (T, L) YLT: each layer priced alone.
+
     Occurrence terms clip each event-occurrence loss per ELT; event losses sum
     across ELTs, accumulate over the trial, then aggregate terms apply:
         l = min(max(l - ret, 0), lim)
     """
+    if jnp.ndim(occ_ret) == 2:
+        return _per_layer(aggregate_loss_ref, event_ids, elt_losses, occ_ret,
+                          occ_lim, agg_ret, agg_lim)
     f32 = jnp.float32
     gathered = elt_losses.astype(f32)[event_ids]          # (T, K, M)
     occ = jnp.clip(gathered - occ_ret[None, None, :], 0.0, None)
@@ -208,6 +222,9 @@ def aggregate_loss_chunked_ref(event_ids, elt_losses, occ_ret, occ_lim,
     """Chunked variant (paper §IV-B "chunking"): identical numerics, processes
     the event axis in fixed-size chunks — the structure the Pallas kernel
     mirrors (one chunk per VMEM tile)."""
+    if jnp.ndim(occ_ret) == 2:
+        return _per_layer(aggregate_loss_chunked_ref, event_ids, elt_losses,
+                          occ_ret, occ_lim, agg_ret, agg_lim, chunk=chunk)
     T, K = event_ids.shape
     assert K % chunk == 0, (K, chunk)
     nck = K // chunk

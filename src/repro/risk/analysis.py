@@ -1,5 +1,9 @@
 """Aggregate Risk Analysis engine (paper Algorithm 1-3) with multi-tenancy.
 
+An analysis prices one layer, or a portfolio of layers over the same ELTs
+(a tower), in one step program: ``RiskTables`` with per-layer terms give a
+``(T, L)`` year-loss table, one layer's terms a ``(T,)`` one.
+
 Three execution paths over the same numerics (kernels/ops.aggregate_loss):
 
 * ``run_single`` — one jit'd call over all trials (baseline, Algorithm 1 with
@@ -25,16 +29,19 @@ Hot-path overhead control (all observable, asserted in tests/test_pipeline.py):
   once and cached on the engine keyed by table identity, so repeated runs
   (serving bursts, ``examples/risk_realtime.py``) stop re-staging ~120 MB per
   step; ``table_uploads`` counts actual uploads.  Layer aggregate terms stay
-  dynamic scalars — what-if pricing perturbs them without touching the cache.
+  dynamic arguments (a scalar pair, or ``(L,)`` each for a tower) — what-if
+  pricing perturbs them without touching the cache or recompiling.
 
 Spans (``repro.obs``; one attribute check each on a disabled plane): an
-analysis is ``risk.analysis`` > ``risk.plan``, ``risk.tables``, then the
+analysis is ``risk.analysis`` (attr ``layers``) > ``risk.plan``,
+``risk.tables``, then the
 executor's ``pipeline.start``, ``risk.slice`` / ``transfer.put`` /
 ``transfer.wait`` / ``pipeline.dispatch`` per tenant and
-``pipeline.drain``, then ``risk.gather`` (YLT reassembly, which reads the
-results back).  Each dispatched step counts ``risk.lookup.<path>``, the
-lookup its program was traced with (``kernels.ops.aggregate_lookup``:
-``vmem`` or ``xla`` on the Pallas gather).
+``pipeline.drain``, then ``risk.gather`` (YLT reassembly, every layer,
+which reads the results back).  Each dispatched step counts
+``risk.lookup.<path>``, the lookup its program was traced with
+(``kernels.ops.aggregate_lookup``: ``vmem`` or ``xla`` on the Pallas
+gather, ``vmem.layers8`` for a tower of 8 layers).
 """
 from __future__ import annotations
 
@@ -89,8 +96,9 @@ class AggregateRiskAnalysis:
                                       devices or jax.devices())
         self._step = jax.jit(self._trial_losses, static_argnames=("chunk",))
         self.trace_count = 0          # incremented at trace time only
-        # ELT rows -> the risk.lookup.* counter of the traced step
-        self._lookup_counters: Dict[int, str] = {}
+        # (ELT shape, occurrence-term shape) -> the risk.lookup.* counter of
+        # the traced step
+        self._lookup_counters: Dict[Tuple, str] = {}
         self.table_uploads = 0        # host->device ELT/term table stagings
         # key -> (host refs pinning the key's id()s, {pdev: device arrays})
         self._table_cache: "collections.OrderedDict[Tuple, Tuple]" = \
@@ -100,8 +108,9 @@ class AggregateRiskAnalysis:
     def _trial_losses(self, yet, elt, occ_ret, occ_lim, agg_ret, agg_lim,
                       chunk: int):
         self.trace_count += 1         # side effects run only while tracing
-        self._lookup_counters[elt.shape[0]] = \
-            "risk.lookup." + kops.aggregate_lookup(elt.shape[0])
+        layers = occ_ret.shape[0] if occ_ret.ndim == 2 else None
+        self._lookup_counters[elt.shape, occ_ret.shape] = "risk.lookup." + \
+            kops.aggregate_lookup(elt.shape[0], layers=layers)
         return kops.aggregate_loss(yet, elt, occ_ret, occ_lim, agg_ret,
                                    agg_lim, chunk=chunk)
 
@@ -114,19 +123,22 @@ class AggregateRiskAnalysis:
         """Cheap content check guarding the id()-keyed cache against
         in-place mutation.  Small arrays (the per-ELT occurrence terms) are
         fingerprinted in full; the large ELT table by shape/dtype plus a
-        strided ``_FP_SAMPLES``-element sample, staying O(1) in table size.
+        strided ``_FP_SAMPLES``-element sample (every ``size // samples``-th
+        element in C order), read in place whatever the array's layout, so
+        it stays O(1) in table size for a column-major table too (a TPU's
+        ``device_get`` of the ELT is one).
         This is a *tripwire*, not a guarantee: a sparse in-place edit of the
         big table can slip past the sample (see the cache contract in
         :meth:`_resident_tables`)."""
         out = []
         for a in host:
-            flat = a.reshape(-1)
-            if flat.size <= 4 * cls._FP_SAMPLES:
-                out.append((a.shape, str(a.dtype), flat.tobytes()))
+            if a.size <= 4 * cls._FP_SAMPLES:
+                out.append((a.shape, str(a.dtype), a.tobytes()))
             else:
-                step = max(1, flat.size // cls._FP_SAMPLES)
-                out.append((a.shape, str(a.dtype),
-                            flat[::step][:cls._FP_SAMPLES].tobytes()))
+                step = max(1, a.size // cls._FP_SAMPLES)
+                at = np.unravel_index(
+                    np.arange(cls._FP_SAMPLES) * step, a.shape)
+                out.append((a.shape, str(a.dtype), a[at].tobytes()))
         return tuple(out)
 
     def _resident_tables(self, tables: RiskTables) -> Dict[int, Tuple]:
@@ -195,14 +207,14 @@ class AggregateRiskAnalysis:
         benchmark harness can measure what the overlap buys.
         """
         tel = self.tel
-        with tel.span("risk.analysis"):
+        with tel.span("risk.analysis", layers=tables.num_layers):
             t_start = time.perf_counter()
             with tel.span("risk.plan"):
                 tasks = self.pool.plan(tables.num_trials, uniform=True)
             with tel.span("risk.tables"):
                 resident = self._resident_tables(tables)
-            agg_ret = np.float32(tables.agg_ret)
-            agg_lim = np.float32(tables.agg_lim)
+            agg_ret = np.asarray(tables.agg_ret, np.float32)
+            agg_lim = np.asarray(tables.agg_lim, np.float32)
             chunk = min(self.cfg.chunk_events, tables.yet.shape[1])
 
             def stage_fn(t: TenantTask):
@@ -217,10 +229,10 @@ class AggregateRiskAnalysis:
                 elt, occ_ret, occ_lim = resident[t.pdev]
                 out = self._step(arrays["yet"], elt, occ_ret, occ_lim,
                                  agg_ret, agg_lim, chunk=chunk)
-                tel.count(self._lookup_counters[elt.shape[0]])
+                tel.count(self._lookup_counters[elt.shape, occ_ret.shape])
                 return out
 
-            ylt = np.zeros(tables.num_trials, np.float32)
+            ylt = np.zeros(tables.ylt_shape, np.float32)
             if overlapped:
                 ex = PipelineExecutor(self.pool, telemetry=tel)
                 rep = ex.run(tasks, stage_fn, compute_fn, straggler_hist)
@@ -271,11 +283,12 @@ class AggregateRiskAnalysis:
         T = num_trials or cfg.num_trials
         K, M, cat = cfg.events_per_trial, cfg.num_elts, cfg.event_catalog
         f32, i32 = jnp.float32, jnp.int32
+        layer = (cfg.num_layers,) if cfg.num_layers > 1 else ()
         return {
             "yet": jax.ShapeDtypeStruct((T, K), i32),
             "elt": jax.ShapeDtypeStruct((cat + 1, M), f32),
-            "occ_ret": jax.ShapeDtypeStruct((M,), f32),
-            "occ_lim": jax.ShapeDtypeStruct((M,), f32),
-            "agg_ret": jax.ShapeDtypeStruct((), f32),
-            "agg_lim": jax.ShapeDtypeStruct((), f32),
+            "occ_ret": jax.ShapeDtypeStruct(layer + (M,), f32),
+            "occ_lim": jax.ShapeDtypeStruct(layer + (M,), f32),
+            "agg_ret": jax.ShapeDtypeStruct(layer, f32),
+            "agg_lim": jax.ShapeDtypeStruct(layer, f32),
         }

@@ -170,3 +170,126 @@ def test_int32_vs_int64_ids_and_f32(rng):
     args[0] = args[0].astype(jnp.int32)
     got = aggregate_loss_pallas(*args, chunk=16)
     np.testing.assert_allclose(np.asarray(got32), np.asarray(got))
+
+
+# ---------------------------------------------------------------------------
+# a portfolio of layers (a tower): terms (L, M) and (L,), a (T, L) YLT
+# ---------------------------------------------------------------------------
+def _tower_case(rng, T, K, M, cat, L):
+    """Seeded layers over one catalog: ids with pad events (0) and the last
+    row, and in every layer past the first an ELT it does not cover (a zero
+    occurrence limit)."""
+    ids, elt, *_ = _case(rng, T, K, M, cat)
+    ids = ids.at[:, ::5].set(0).at[0, 1].set(cat)
+    att = np.sort(rng.uniform(0.0, 1.5, (L + 1, M)), axis=0)
+    ret, lim = att[:-1], att[1:] - att[:-1]
+    lim[1:, -1] = 0.0
+    agg_ret = rng.uniform(0.0, 0.1 * K, L)
+    agg_lim = rng.uniform(0.2 * K, 0.6 * K, L)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    return ids, elt, f32(ret), f32(lim), f32(agg_ret), f32(agg_lim)
+
+
+@pytest.mark.parametrize("L", [1, 3, 8])
+@pytest.mark.parametrize("T,K,M,cat,chunk,tb", [
+    (37, 96, 5, 700, 32, 16),      # ragged trials, rows not a multiple
+    (64, 128, 15, 300, 128, None),
+])
+def test_tower_matches_oracle(rng, T, K, M, cat, chunk, tb, L):
+    """The VMEM lookup prices every layer of an event in one pass: equal to
+    the oracle, each layer priced alone, with uncovered ELTs, pad events and
+    a ragged trial count."""
+    args = _tower_case(rng, T, K, M, cat, L)
+    assert agg.lookup_path(cat + 1, L) == "vmem"
+    got = np.asarray(aggregate_loss_pallas(*args, chunk=chunk,
+                                           trial_block=tb))
+    want = np.asarray(aggregate_loss_chunked_ref(*args, chunk=chunk))
+    assert got.shape == want.shape == (T, L)
+    assert (want > 0).any(axis=0).all()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
+    one = aggregate_loss_ref(*args)
+    np.testing.assert_allclose(np.asarray(one), want, rtol=1e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("variant", ["gather", "onehot", "xla"])
+def test_tower_variants_match_oracle(rng, variant, monkeypatch):
+    """The onehot kernel prices a tower a layer at a time, and a table over
+    the budget falls back to XLA's gather of it, with the same answers."""
+    args = _tower_case(rng, 24, 64, 4, 500, 5)
+    if variant == "xla":
+        monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 4 * 500)
+        assert agg.lookup_path(501, 5) == "xla"
+        variant = "gather"
+    got = aggregate_loss_pallas(*args, chunk=32, variant=variant)
+    want = aggregate_loss_chunked_ref(*args, chunk=32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("pallas", [True, False])
+def test_one_layer_without_the_axis_is_the_one_layer_program(rng, pallas):
+    """``(M,)`` terms give the ``(T,)`` YLT, bit for bit the column that
+    the same terms given as one layer, ``(1, M)``, give."""
+    from repro.kernels import ops as kops
+    ids, elt, ret, lim, agg_ret, agg_lim = _case(rng, 40, 64, 6, 400)
+    prev = kops.pallas_enabled()
+    kops.use_pallas(pallas)
+    try:
+        flat = np.asarray(kops.aggregate_loss(ids, elt, ret, lim, agg_ret,
+                                              agg_lim, chunk=32))
+        layered = np.asarray(kops.aggregate_loss(
+            ids, elt, ret[None], lim[None], jnp.asarray([agg_ret]),
+            jnp.asarray([agg_lim]), chunk=32))
+    finally:
+        kops.use_pallas(prev)
+    assert flat.shape == (40,) and layered.shape == (40, 1)
+    np.testing.assert_array_equal(flat, layered[:, 0])
+
+
+def test_tower_budget_counts_the_padded_layers(monkeypatch):
+    """The VMEM table holds 4 bytes per catalog row and padded layer: the
+    paper's 2,000,001 rows take 8 MB for one layer and 64 MiB for 8 (or 5:
+    layers pad to a power of two), within the budget; a 16 MiB budget
+    sends the tower to XLA's gather and keeps one layer in VMEM."""
+    rows = 2_000_001
+    assert agg.padded_layers(5) == agg.padded_layers(8) == 8
+    assert agg.table_rows(rows, 1) == 15_626
+    assert agg.table_rows(rows, 8) == agg.table_rows(rows, 5) == 131_072
+    assert 4 * 128 * agg.table_rows(rows, 8) == 64 << 20
+    assert agg.lookup_path(rows, 8) == "vmem"
+    monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 16 << 20)
+    assert agg.lookup_path(rows, 1) == "vmem"
+    assert agg.lookup_path(rows, 8) == "xla"
+    with pytest.raises(ValueError, match="1 to 128"):
+        agg.padded_layers(129)
+
+
+def test_tower_steps_count_their_layered_lookup():
+    """On an enabled plane, each step of a tower's analysis counts the
+    layered lookup its program compiled, and ``risk.analysis`` carries the
+    layer count."""
+    import dataclasses
+    from repro.configs.risk_app import RiskAppConfig
+    from repro.core.tenancy import TenancyConfig
+    from repro.kernels import ops as kops
+    from repro.obs.telemetry import Telemetry
+    from repro.risk.analysis import AggregateRiskAnalysis
+    from repro.risk.tables import generate
+    cfg = dataclasses.replace(RiskAppConfig().reduced(), num_layers=8)
+    tables = generate(cfg, seed=0)
+    want = AggregateRiskAnalysis(cfg, TenancyConfig(1, 2)).run_single(tables)
+    tel = Telemetry(enabled=True)
+    prev = kops.pallas_enabled()
+    kops.use_pallas(True)
+    try:
+        ara = AggregateRiskAnalysis(cfg, TenancyConfig(1, 2), telemetry=tel)
+        ylt = ara.run_tenant_chunked(tables).ylt
+    finally:
+        kops.use_pallas(prev)
+    lookups = {k: v for k, v in tel.counter_snapshot().items()
+               if k.startswith("risk.lookup.")}
+    assert lookups == {"risk.lookup.vmem.layers8": 2}
+    root, = tel.spans(name="risk.analysis")
+    assert root.attrs == {"layers": 8}
+    assert ylt.shape == want.shape == (cfg.num_trials, 8)
+    np.testing.assert_allclose(ylt, want, rtol=1e-5, atol=1e-3)

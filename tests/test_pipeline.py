@@ -119,6 +119,24 @@ def test_resident_cache_detects_inplace_mutation(cfg, tables):
     assert ara.table_uploads > uploads
 
 
+def test_table_fingerprint_reads_a_column_major_table_in_place():
+    """The cache check on every analysis samples the ELT where it lies: a
+    column-major table (what a TPU's ``device_get`` gives) fingerprints as
+    its row-major copy does, without copying the table."""
+    import tracemalloc
+    rng = np.random.default_rng(0)
+    elt = np.asfortranarray(rng.random((200_001, 15), dtype=np.float32))
+    terms = np.ones((8, 15), np.float32)
+    want = AggregateRiskAnalysis._table_fingerprint(
+        (np.ascontiguousarray(elt), terms))
+    tracemalloc.start()
+    got = AggregateRiskAnalysis._table_fingerprint((elt, terms))
+    peak = tracemalloc.get_traced_memory()[1]
+    tracemalloc.stop()
+    assert got == want
+    assert peak < elt.nbytes // 100, peak
+
+
 def test_sequential_timeline_overlaps(cfg):
     """transfer(k+1) starts inside compute(k)'s window — the paper's
     overlap, with the falsifiable predicate from core.pipeline.  Uses a

@@ -108,3 +108,65 @@ def test_sharded_step_single_device(cfg, tables):
                jnp.asarray(tables.occ_ret), jnp.asarray(tables.occ_lim),
                jnp.asarray(tables.agg_ret), jnp.asarray(tables.agg_lim))
     np.testing.assert_allclose(np.asarray(ylt), _ref_ylt(tables), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# a portfolio of layers: a catastrophe excess-of-loss tower
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def tower_cfg(cfg):
+    return dataclasses.replace(cfg, num_layers=8)
+
+
+@pytest.fixture(scope="module")
+def tower(tower_cfg):
+    return generate(tower_cfg, seed=3)
+
+
+def test_generated_tower_stacks_its_layers(tower_cfg, tower):
+    """Each layer attaches where the one below exhausts; every term is per
+    layer, and the one-layer tables are the tower's first attachment."""
+    L, M = tower_cfg.num_layers, tower_cfg.num_elts
+    assert tower.num_layers == L and tower.ylt_shape == (64, L)
+    assert tower.occ_ret.shape == tower.occ_lim.shape == (L, M)
+    assert tower.agg_ret.shape == tower.agg_lim.shape == (L,)
+    np.testing.assert_allclose(tower.occ_ret[1:],
+                               tower.occ_ret[:-1] + tower.occ_lim[:-1],
+                               rtol=1e-6)
+    assert (tower.occ_lim > 0).all() and (tower.agg_ret > 0).all()
+    one = generate(RiskAppConfig().reduced(), seed=3)
+    np.testing.assert_array_equal(one.yet, tower.yet)
+    np.testing.assert_array_equal(one.occ_ret, tower.occ_ret[0])
+    assert one.num_layers == 1 and one.ylt_shape == (64,)
+
+
+def test_tenant_chunked_tower_matches_plain_reference(tower_cfg, tower):
+    """``run_tenant_chunked`` prices all 8 layers in one step a tenant chunk,
+    equal to the plain per-layer Algorithm 3 of the benchmark."""
+    from bench.reference import risk_tower as reference
+    ara = AggregateRiskAnalysis(tower_cfg, TenancyConfig(1, 2))
+    ylt = ara.run_tenant_chunked(tower).ylt
+    loss = reference.trial_losses(tower.yet, tower.elt_losses,
+                                  tower.occ_ret, tower.occ_lim)
+    want = reference.year_losses(loss, tower.agg_ret, tower.agg_lim)
+    assert ylt.shape == want.shape == (64, 8)
+    assert (want > 0).any(axis=0).all()
+    np.testing.assert_allclose(ylt, want, rtol=1e-5,
+                               atol=1e-6 * float(loss.max()))
+    assert ara.trace_count == 1
+
+
+def test_new_tower_terms_neither_compile_nor_upload(tower_cfg, tower):
+    """The per-layer aggregate terms are a dynamic argument: a second
+    analysis under new terms compiles nothing and uploads no table."""
+    ara = AggregateRiskAnalysis(tower_cfg, TenancyConfig(1, 2))
+    first = ara.run_tenant_chunked(tower).ylt
+    traces, uploads = ara.trace_count, ara.table_uploads
+    scale = np.linspace(0.5, 1.5, 8).astype(np.float32)
+    what_if = dataclasses.replace(tower, agg_ret=tower.agg_ret * scale,
+                                  agg_lim=tower.agg_lim * scale[::-1])
+    second = ara.run_tenant_chunked(what_if).ylt
+    assert (ara.trace_count, ara.table_uploads) == (traces, uploads) == (1, 1)
+    assert not np.array_equal(first, second)
+    np.testing.assert_allclose(
+        second, ara.run_single(what_if), rtol=1e-6)

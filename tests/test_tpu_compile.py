@@ -1,9 +1,9 @@
 """The main path's Pallas kernels compile for a described TPU v5e chip at the
 widths they run at: the paged decode read and the admission scatter at
 internlm2-1.8b widths, the aggregate-loss kernel at the paper's risk widths
-(1000 events per trial, 15 ELTs, a 2,000,000-event catalog: the VMEM lookup,
-and XLA's gather with the budget lowered) and the SSD scan at mamba2-2.7b
-widths.
+(1000 events per trial, 15 ELTs, a 2,000,000-event catalog: the VMEM lookup
+for one layer and for a tower of 8, and XLA's gather with the budget
+lowered) and the SSD scan at mamba2-2.7b widths.
 
 Nothing runs: the TPU compiler checks tiling, VMEM use and lowering, which
 interpret mode on the CPU cannot.  The topology is described inside a
@@ -88,9 +88,12 @@ def test_paged_scatter_compiles(one_chip, no_compile_cache):
              ((L, nb, PAGE, Hkv, D), jnp.bfloat16))
 
 
-def _compile_aggregate_loss(sharding, variant, kernel=True):
+def _compile_aggregate_loss(sharding, variant, kernel=True, layers=None):
+    """``layers``: a tower's layer count (terms with a layer axis), or None
+    for one layer's terms."""
     K = -(-RISK.events_per_trial // RISK.chunk_events) * RISK.chunk_events
     M = RISK.num_elts
+    lay = () if layers is None else (layers,)
     compiled = _compile(
         lambda *a: agg.aggregate_loss_pallas(*a, chunk=RISK.chunk_events,
                                              interpret=False,
@@ -98,8 +101,8 @@ def _compile_aggregate_loss(sharding, variant, kernel=True):
         sharding,
         ((RISK_TRIALS, K), jnp.int32), ((RISK.event_catalog + 1, M),
                                         jnp.float32),
-        ((M,), jnp.float32), ((M,), jnp.float32), ((), jnp.float32),
-        ((), jnp.float32), kernel=kernel)
+        (lay + (M,), jnp.float32), (lay + (M,), jnp.float32),
+        (lay, jnp.float32), (lay, jnp.float32), kernel=kernel)
     # the whole program, any relaid table copy included, fits one chip
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
@@ -116,11 +119,27 @@ def test_aggregate_loss_compiles(one_chip, no_compile_cache, variant):
         assert mem.temp_size_in_bytes < 64e6
 
 
+def test_tower_lookup_compiles(one_chip, no_compile_cache):
+    """A tower of 8 layers at the paper's widths: every layer of an event
+    in one VMEM lookup, the 64 MiB table within the budget, and no copy of
+    the table padded to 128 lanes a catalog row (1 GB)."""
+    assert agg.lookup_path(RISK.event_catalog + 1, 8) == "vmem"
+    mem = _compile_aggregate_loss(one_chip, "gather", layers=8)
+    assert mem.output_size_in_bytes == 4 * RISK_TRIALS * 8
+    assert mem.temp_size_in_bytes < 200e6
+
+
 def test_aggregate_loss_xla_fallback_compiles(one_chip, no_compile_cache,
                                               monkeypatch):
     monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 1 << 20)
     assert agg.lookup_path(RISK.event_catalog + 1) == "xla"
     _compile_aggregate_loss(one_chip, "gather", kernel=False)
+
+
+def test_tower_xla_fallback_compiles(one_chip, no_compile_cache, monkeypatch):
+    monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 16 << 20)
+    assert agg.lookup_path(RISK.event_catalog + 1, 8) == "xla"
+    _compile_aggregate_loss(one_chip, "gather", kernel=False, layers=8)
 
 
 def test_ssd_scan_compiles(one_chip, no_compile_cache):
