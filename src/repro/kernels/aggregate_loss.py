@@ -24,17 +24,25 @@ limit on it.
   - ``vmem`` — where ``f``, laid out lane-dense, fits
     :data:`LOOKUP_VMEM_BUDGET` (the paper's 2,000,001 rows do, for one
     layer in 8 MB and for eight in 64 MiB), it is fetched into VMEM once a
-    call, single-buffered.  Grid = (trial_blocks, event_chunks), whole
-    rows of event ids a step where they fit :data:`LOOKUP_SMEM_BUDGET`;
-    for each event the kernel reads the id from SMEM, loads the event's
-    row of the table with one dynamic sublane load and adds the event's
-    lanes into the trial's lane accumulator (compare with a lane iota,
-    select, add).  No DMA per event.  One layer's table is (rows / 128,
-    128): row ``id >> 7``, lane ``id & 127``.  A tower's layers are padded
-    to a power of two, Lp, and interleaved so that one load brings every
-    layer of an event: a (R, 128) table, R a power of two of at least
-    rows / G with G = 128 / Lp, catalog row ``id`` at row ``id & (R -
-    1)``, lanes ``(id >> log2 R) * Lp + l`` for its layers ``l``
+    call, single-buffered.  Grid = (trial_blocks, event_chunks), a
+    trial's whole event ids a step where they fit
+    :data:`LOOKUP_SMEM_BUDGET`.  The ids reach SMEM in rows of 128 events
+    (of K where K is under 128), the (8, 128) tiles that (T, K) has in
+    HBM, so no copy makes them; the kernel walks a trial's rows and
+    unrolls each row, so an id's SMEM address is its row's plus a
+    constant.  For each event it loads the id, loads the event's row of
+    the table with one dynamic sublane load and adds the event's lanes
+    into the trial's lane accumulator (the id broadcast over the lanes,
+    its slot compared with a lane iota, select, add).  Besides the id's
+    load, an event takes three scalar operations (the id's address, the
+    table row, the row's address); the rest is vector work, and the adds
+    into one accumulator are the chain that bounds the loop.  No DMA per
+    event.  One layer's table is (rows / 128, 128): row ``id >> 7``, lane
+    ``id & 127``.  A tower's layers are padded to a power of two, Lp, and
+    interleaved so that one load brings every layer of an event: a (R,
+    128) table, R a power of two of at least rows / G with G = 128 / Lp,
+    catalog row ``id`` at row ``id & (R - 1)``, lanes ``(id >> log2 R) *
+    Lp + l`` for its layers ``l``
     (:func:`_tower_table`; built by a 2-D transpose of the layer-major
     combine).  The last chunk adds the accumulator's G lane groups into
     the trial's Lp layer losses, by rolls.
@@ -82,9 +90,6 @@ LOOKUP_VMEM_BUDGET = 96 << 20
 LOOKUP_SMEM_BUDGET = 256 << 10
 # trials per grid step on the ``vmem`` path, where their ids fit the budget
 LOOKUP_TRIAL_BLOCK = 32
-# events per iteration of the ``vmem`` path's event loop: on a v5e, 32 took
-# 0.279 s a 65,536 x 1,024-event step, 8 0.320 s, 16 0.284 s, 64 0.293 s
-LOOKUP_UNROLL = 32
 
 
 def _round_up(x: int, m: int) -> int:
@@ -149,14 +154,17 @@ def _tower_table(f, rows_out: int):
 
 
 def _vmem_kernel(ids_ref, f_ref, agg_ref, out_ref, acc_ref, *,
-                 n_chunks: int, unroll: int, layers: int):
+                 n_chunks: int, layers: int):
     j = pl.program_id(1)
-    tb, c = ids_ref.shape
+    tb = acc_ref.shape[0]
+    n_rows, width = ids_ref.shape[1], ids_ref.shape[3]  # a trial's ids' rows
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # the row's address is scalar work; the slot is taken on the vector
+    # unit from the id broadcast over the lanes
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
     if layers == 1:     # row i >> 7, lane i & 127
         row_of = lambda i: i >> 7
@@ -168,15 +176,16 @@ def _vmem_kernel(ids_ref, f_ref, agg_ref, out_ref, acc_ref, *,
         lane = lane >> (layers.bit_length() - 1)    # the slot of each lane
 
     def trial(t, carry):
-        def events(k, acc):        # Mosaic unrolls by hand only
-            for u in range(unroll):
-                i = ids_ref[t, k * unroll + u]
+        def events(q, acc):     # one row of ids, its columns static
+            for u in range(width):
+                i = ids_ref[t >> 3, q, t & 7, u]
                 row = f_ref[pl.ds(row_of(i), 1), :]
-                acc = acc + jnp.where(lane == slot_of(i), row, 0.0)
+                slot = slot_of(jnp.broadcast_to(i, lane.shape))
+                acc = acc + jnp.where(lane == slot, row, 0.0)
             return acc
 
         acc_ref[pl.ds(t, 1), :] = jax.lax.fori_loop(
-            0, c // unroll, events, acc_ref[pl.ds(t, 1), :])
+            0, n_rows, events, acc_ref[pl.ds(t, 1), :])
         return carry
 
     jax.lax.fori_loop(0, tb, trial, 0)
@@ -199,12 +208,12 @@ def _vmem_kernel(ids_ref, f_ref, agg_ref, out_ref, acc_ref, *,
             agg_ref[1:2, :])
 
 
-def _vmem_lookup(ids, f, agg, *, chunk: int, trial_block: Optional[int],
+def _vmem_lookup(ids, f, agg, *, trial_block: Optional[int],
                  interpret: bool):
-    """The ``vmem`` path of the gather variant: ``ids`` is (T, K) with K a
-    multiple of ``chunk``; ``f`` the (rows,) combined occurrence losses and
-    ``agg`` the (2,) aggregate terms of one layer, or ``f`` (L, rows) and
-    ``agg`` (2, L) for L layers.  Returns the (T,) or (T, L) YLT."""
+    """The ``vmem`` path of the gather variant: ``ids`` is (T, K); ``f``
+    the (rows,) combined occurrence losses and ``agg`` the (2,) aggregate
+    terms of one layer, or ``f`` (L, rows) and ``agg`` (2, L) for L layers.
+    Returns the (T,) or (T, L) YLT."""
     T, K = ids.shape
     layered = f.ndim == 2
     layers, rows = f.shape if layered else (1, f.shape[0])
@@ -215,26 +224,31 @@ def _vmem_lookup(ids, f, agg, *, chunk: int, trial_block: Optional[int],
         agg = jnp.pad(agg, ((0, 0), (0, lp - layers)))
     else:
         f = jnp.pad(f, (0, R * LANES - rows)).reshape(R, LANES)
-    # whole rows of ids a step where eight trials' fit the SMEM budget
-    c = K if 4 * 8 * K <= LOOKUP_SMEM_BUDGET else chunk
-    tb = trial_block or max(8, min(LOOKUP_TRIAL_BLOCK,
-                                   LOOKUP_SMEM_BUDGET // (4 * c) // 8 * 8))
+    # events in rows of 128, or of K under 128 (pad event 0 prices
+    # nothing), in the order of the (8, 128) tiles of (T, K), which is how
+    # (T, K) lies in HBM: ids[a, q, r] is trial 8a + r's row q.  A step
+    # takes its trials' whole rows where eight trials' fit the SMEM budget,
+    # else one row a trial
+    width = min(K, LANES)
+    k_rows = -(-K // width)
+    c_rows = k_rows if 4 * 8 * LANES * k_rows <= LOOKUP_SMEM_BUDGET else 1
+    tb = trial_block or max(8, min(LOOKUP_TRIAL_BLOCK, LOOKUP_SMEM_BUDGET
+                                   // (4 * LANES * c_rows) // 8 * 8))
     tb = min(_round_up(tb, 8), _round_up(T, 8))
     T_pad = _round_up(T, tb)
-    if T_pad != T:
-        ids = jnp.pad(ids, ((0, T_pad - T), (0, 0)))
-    n_chunks = K // c
+    ids = jnp.pad(ids, ((0, T_pad - T), (0, width * k_rows - K)))
+    ids = ids.reshape(T_pad // 8, 8, k_rows, width).transpose(0, 2, 1, 3)
+    n_chunks = k_rows // c_rows
     f_bytes = 4 * R * LANES
     # one layer's terms are SMEM scalars; a tower's a (2, Lp) VMEM block
     agg_spec = (pl.BlockSpec((2, lp), lambda i, j: (0, 0)) if layered else
                 pl.BlockSpec(memory_space=pltpu.SMEM))
     out = pl.pallas_call(
-        functools.partial(_vmem_kernel, n_chunks=n_chunks,
-                          unroll=math.gcd(c, LOOKUP_UNROLL), layers=lp),
+        functools.partial(_vmem_kernel, n_chunks=n_chunks, layers=lp),
         grid=(T_pad // tb, n_chunks),
         in_specs=[
-            pl.BlockSpec((tb, c), lambda i, j: (i, j),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((tb // 8, c_rows, 8, width),
+                         lambda i, j: (i, j, 0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((R, LANES), lambda i, j: (0, 0),
                          pipeline_mode=pl.Buffered(1)),
             agg_spec,
@@ -339,8 +353,8 @@ def aggregate_loss_pallas(event_ids, elt_losses, occ_ret, occ_lim, agg_ret,
         f = _combined_losses(elt, occ_ret, occ_lim)
         layers = f.shape[0] if f.ndim == 2 else 1
         if lookup_path(rows, layers) == "vmem":
-            return _vmem_lookup(ids, f, agg, chunk=chunk,
-                                trial_block=trial_block, interpret=interpret)
+            return _vmem_lookup(ids, f, agg, trial_block=trial_block,
+                                interpret=interpret)
         y = f.T[ids].sum(axis=1)
         return jnp.minimum(jnp.clip(y - agg[0], 0.0, None), agg[1])
 

@@ -1,4 +1,5 @@
 """aggregate_loss Pallas kernel vs pure-jnp oracle: shape/dtype sweeps."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -293,3 +294,66 @@ def test_tower_steps_count_their_layered_lookup():
     assert root.attrs == {"layers": 8}
     assert ylt.shape == want.shape == (cfg.num_trials, 8)
     np.testing.assert_allclose(ylt, want, rtol=1e-5, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# the VMEM lookup's id blocks: rows of 128 events, whole or a row at a time
+# ---------------------------------------------------------------------------
+def _rows_case(rng, T, K, L, cat=700):
+    """One layer's terms (``L`` None) or a tower of ``L``, with every trial's
+    first event the pad row and its middle and last the last catalog row."""
+    if L is None:
+        ids, *rest = _case(rng, T, K, 6, cat)
+    else:
+        ids, *rest = _tower_case(rng, T, K, 6, cat, L)
+    ids = ids.at[:, 0].set(0).at[:, -1].set(cat).at[:, K // 2].set(cat)
+    return (ids, *rest)
+
+
+def _vmem_grid(args, **kw):
+    """The grid of the VMEM lookup that ``aggregate_loss_pallas`` runs."""
+    jaxpr = jax.make_jaxpr(
+        lambda *a: aggregate_loss_pallas(*a, **kw))(*args).jaxpr
+    grids = [e.params["grid_mapping"].grid for e in jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(grids) == 1
+    return grids[0]
+
+
+@pytest.mark.parametrize("L", [None, 8])
+@pytest.mark.parametrize("T,K,tb", [
+    (13, 40, 8),        # K under 128: one row of K events
+    (21, 200, 16),      # K not a multiple of 128, trials ragged
+    (16, 128, None),    # one whole row
+    (9, 1024, 8),       # the paper's padded K: eight rows, trials ragged
+])
+def test_vmem_rows_of_128_match_oracle(rng, T, K, tb, L):
+    """The VMEM lookup reads a trial's ids in rows of 128 events (one row of
+    K under 128), K padded with event 0 up to whole rows: equal to the
+    oracle for one layer and for a tower, with the pad row and the last
+    catalog row among the ids."""
+    args = _rows_case(rng, T, K, L)
+    assert agg.lookup_path(701, L or 1) == "vmem"
+    assert _vmem_grid(args, trial_block=tb)[1] == 1     # whole rows a step
+    got = np.asarray(aggregate_loss_pallas(*args, trial_block=tb))
+    want = np.asarray(aggregate_loss_chunked_ref(*args,
+                                                 chunk=np.gcd(K, 128)))
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("L", [None, 8])
+def test_vmem_chunked_id_blocks_match_whole_rows(rng, monkeypatch, L):
+    """Where a trial block's whole rows of ids do not fit the SMEM budget
+    the kernel takes one row of each trial a step: the same events in the
+    same order into the same lanes, so the YLT is bit for bit the one of
+    whole rows, and the oracle's."""
+    args = _rows_case(rng, 19, 300, L)
+    whole = np.asarray(aggregate_loss_pallas(*args))
+    assert _vmem_grid(args) == (1, 1)
+    monkeypatch.setattr(agg, "LOOKUP_SMEM_BUDGET", 4 * 8 * 128 * 2)
+    assert _vmem_grid(args) == (2, 3)       # 16 trials a block, 3 rows
+    chunked = np.asarray(aggregate_loss_pallas(*args))
+    np.testing.assert_array_equal(chunked, whole)
+    want = np.asarray(aggregate_loss_chunked_ref(*args, chunk=100))
+    np.testing.assert_allclose(chunked, want, rtol=1e-5, atol=1e-3)

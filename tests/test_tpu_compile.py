@@ -2,7 +2,8 @@
 widths they run at: the paged decode read and the admission scatter at
 internlm2-1.8b widths, the aggregate-loss kernel at the paper's risk widths
 (1000 events per trial, 15 ELTs, a 2,000,000-event catalog: the VMEM lookup
-for one layer and for a tower of 8, and XLA's gather with the budget
+for one layer and for a tower of 8, with whole rows of ids a step or, the
+SMEM budget lowered, one row a trial, and XLA's gather with the VMEM budget
 lowered) and the SSD scan at mamba2-2.7b widths.
 
 Nothing runs: the TPU compiler checks tiling, VMEM use and lowering, which
@@ -127,6 +128,20 @@ def test_tower_lookup_compiles(one_chip, no_compile_cache):
     mem = _compile_aggregate_loss(one_chip, "gather", layers=8)
     assert mem.output_size_in_bytes == 4 * RISK_TRIALS * 8
     assert mem.temp_size_in_bytes < 200e6
+
+
+@pytest.mark.parametrize("layers", [None, 8])
+def test_vmem_chunked_id_blocks_compile(one_chip, no_compile_cache,
+                                        monkeypatch, layers):
+    """With an SMEM budget under eight trials' whole rows of ids, the VMEM
+    lookup takes one row of 128 ids a trial a step, at the paper's widths,
+    and still reads the ids where they lie, with no copy of them."""
+    monkeypatch.setattr(agg, "LOOKUP_SMEM_BUDGET", 16 << 10)
+    K = -(-RISK.events_per_trial // RISK.chunk_events) * RISK.chunk_events
+    assert 4 * 8 * K > agg.LOOKUP_SMEM_BUDGET
+    assert agg.lookup_path(RISK.event_catalog + 1, layers or 1) == "vmem"
+    mem = _compile_aggregate_loss(one_chip, "gather", layers=layers)
+    assert mem.temp_size_in_bytes < (64e6 if layers is None else 200e6)
 
 
 def test_aggregate_loss_xla_fallback_compiles(one_chip, no_compile_cache,
