@@ -1,13 +1,11 @@
 """One benchmark per paper table/figure.
 
 Each `bench_*` returns a list of (name, us_per_call, derived) rows.  Model
-and simulator rows derive from the paper's Table II constants; `measured`
-rows time the real CPU-reduced stack (jit'd ARA engine + staging engine), so
-the harness exercises every layer it reports on.
+and simulator rows derive from the paper's Table II constants; the
+`kernels` rows time the jitted ops on the CPU.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 from typing import List, Tuple
 
@@ -17,7 +15,7 @@ Row = Tuple[str, float, str]
 
 
 # ---------------------------------------------------------------------------
-# Table I + Fig 1 + Fig 6 — local scalability & compute/transfer split
+# Table I + Fig 1 + Fig 6 — local scalability
 # ---------------------------------------------------------------------------
 def bench_table1_scalability() -> List[Row]:
     from repro.core import perfmodel as pm
@@ -32,32 +30,6 @@ def bench_table1_scalability() -> List[Row]:
         out.append((f"table1/local_cuda_{n}gpu", total * 1e6,
                     f"paper_norm={norm:.3f};offset={offset:.3f};"
                     f"model={model_t:.3f}s"))
-    return out
-
-
-def bench_fig6_split() -> List[Row]:
-    """Measured compute vs staging split on the reduced CPU stack."""
-    import jax.numpy as jnp
-    from repro.configs.risk_app import RiskAppConfig
-    from repro.core.tenancy import TenancyConfig
-    from repro.risk.analysis import AggregateRiskAnalysis
-    from repro.risk.tables import generate
-
-    cfg = dataclasses.replace(RiskAppConfig().reduced(), num_trials=512,
-                              events_per_trial=64)
-    tables = generate(cfg)
-    out: List[Row] = []
-    for splits in (1, 2, 4):
-        ara = AggregateRiskAnalysis(cfg, TenancyConfig(1, splits))
-        # blocking schedule: this bench *decomposes* wall time into compute
-        # vs staging, which only adds up when the phases don't overlap (the
-        # overlapped pipeline has its own A/B bench in benchmarks/pipeline.py)
-        rep = ara.run_tenant_chunked(tables, overlapped=False)   # warm
-        rep = ara.run_tenant_chunked(tables, overlapped=False)
-        compute = sum(rep.per_tenant_s.values())
-        stage = max((e["ready_s"] for e in rep.staging_log), default=0.0)
-        out.append((f"fig6/measured_split_{splits}v", rep.wall_s * 1e6,
-                    f"compute={compute*1e3:.1f}ms;staging={stage*1e3:.1f}ms"))
     return out
 
 
@@ -219,7 +191,7 @@ def bench_kernels() -> List[Row]:
     return out
 
 
-ALL = [bench_table1_scalability, bench_fig6_split, bench_fig8_bandwidth,
+ALL = [bench_table1_scalability, bench_fig8_bandwidth,
        bench_fig9_remote_scaling, bench_fig11_transfer_modes,
        bench_fig13_multitenancy, bench_fig14_energy,
        bench_fig15_16_combinations, bench_fig17_22_models, bench_kernels]

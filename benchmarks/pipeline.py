@@ -1,13 +1,10 @@
-"""Executable-pipeline benchmarks: what the overlap actually buys.
+"""Executable-pipeline benchmarks on the real (CPU-reduced) stack:
 
-Two A/B comparisons on the real (CPU-reduced) stack:
-
-* **blocking vs overlapped** — the legacy stage-all-then-compute schedule
-  against :class:`repro.core.pipeline.PipelineExecutor` (stage k+1 under
-  compute k), same tenancy, same data.  Emits per-tenant transfer/compute
-  windows so the harness can verify transfer(k+1) starts before compute(k)
-  ends, plus resident-table-cache and trace-count rows for the repeated-run
-  (serving) regime.
+* **risk pipeline** — :class:`repro.core.pipeline.PipelineExecutor` (stage
+  k+1 under compute k).  Emits per-tenant transfer/compute windows so the
+  harness can verify transfer(k+1) starts before compute(k) ends, plus
+  resident-table-cache and trace-count rows for the repeated-run (serving)
+  regime.
 * **serving blocking vs overlapped** — the multi-tenant scheduler on the
   engine's host-blocking ``generate`` loop against the dispatch/await split
   (prefill + on-device ``lax.scan`` decode enqueued without blocking, tenant
@@ -15,13 +12,6 @@ Two A/B comparisons on the real (CPU-reduced) stack:
   wall-time rows for both schedules plus the realised overlap-pair count
   from the serving ``TenantTimeline`` (same falsifiable predicate as the
   risk pipeline rows).
-* **gather vs one-hot** — the two aggregate_loss Pallas lookup strategies in
-  interpret mode.  Interpret-mode wall time is an emulation artefact, not
-  device time (the numbers rank Python-level op counts); the structural win
-  of the one-hot path (MXU matmul instead of per-lane gather) only shows on
-  real TPUs — the rows exist to track both variants' health and relative
-  drift.
-
 * **paged-attention backends** — the serving decode's dense jnp KV gather
   against the fused Pallas page-streaming kernel at several (bucket,
   page-size) points, with pages-touched and bytes-moved derived columns
@@ -116,27 +106,21 @@ def bench_pipeline_overlap() -> List[Row]:
     tenancy = TenancyConfig(n_pdev, 2, "sequential")
     ara = AggregateRiskAnalysis(cfg, tenancy, devices=devices)
 
-    # warm both schedules (compile once; uniform padding -> one trace)
-    ara.run_tenant_chunked(tables, overlapped=False)
-    ara.run_tenant_chunked(tables, overlapped=True)
+    ara.run_tenant_chunked(tables)    # warm (uniform padding -> one trace)
 
     out: List[Row] = []
-    t_blk, t_ovl, med_blk, med_ovl = _min_ab(
-        lambda: ara.run_tenant_chunked(tables, overlapped=False),
-        lambda: ara.run_tenant_chunked(tables, overlapped=True))
+    t_ovl = _best_of(lambda: ara.run_tenant_chunked(tables), n=9)
     tag = f"{n_pdev}p_2v"
-    out.append((f"pipeline/blocking_{tag}", t_blk * 1e6,
-                f"trials={cfg.num_trials};median_us={med_blk * 1e6:.0f}"))
     from repro.core.pipeline import timeline_overlaps
-    rep = ara.run_tenant_chunked(tables, overlapped=True)
+    rep = ara.run_tenant_chunked(tables)
     # falsifiable overlap signal: transfer(k+1) began inside compute(k)'s
     # execution window (see repro.core.pipeline module docstring).  A
-    # blocking schedule scores 0 pairs; noise on a shared host can drain
-    # isolated pairs early, so "realised" = majority of pairs overlapped.
+    # schedule that staged everything first would score 0 pairs; noise on a
+    # shared host can drain isolated pairs early, so "realised" = majority
+    # of pairs overlapped.
     overlaps = timeline_overlaps(rep.timeline)
     out.append((f"pipeline/overlapped_{tag}", t_ovl * 1e6,
-                f"speedup={t_blk / t_ovl:.2f}x;"
-                f"median_us={med_ovl * 1e6:.0f};"
+                f"trials={cfg.num_trials};"
                 f"overlap_pairs={sum(overlaps)}/{len(overlaps)};"
                 f"overlap_realised={sum(overlaps) > len(overlaps) // 2}"))
     for tl in rep.timeline:
@@ -589,34 +573,6 @@ def bench_paged_attention() -> List[Row]:
     return out
 
 
-def bench_kernel_variants() -> List[Row]:
-    import jax.numpy as jnp
-    from repro.kernels.aggregate_loss import aggregate_loss_pallas
-    from repro.kernels.ref import aggregate_loss_chunked_ref
-
-    rng = np.random.default_rng(0)
-    T, K, M, cat = 256, 64, 8, 2048
-    ids = jnp.asarray(rng.integers(0, cat + 1, (T, K)).astype(np.int32))
-    elt = np.abs(rng.normal(size=(cat + 1, M))).astype(np.float32)
-    elt[0] = 0.0
-    elt = jnp.asarray(elt)
-    occ_r = jnp.asarray((np.abs(rng.normal(size=M)) * 0.5).astype(np.float32))
-    occ_l = jnp.asarray((np.abs(rng.normal(size=M)) + 1.0).astype(np.float32))
-    args = (ids, elt, occ_r, occ_l, np.float32(K * 0.1), np.float32(K * 0.8))
-    want = np.asarray(aggregate_loss_chunked_ref(*args, chunk=32))
-
-    out: List[Row] = []
-    for variant in ("gather", "onehot"):
-        run = lambda: aggregate_loss_pallas(*args, chunk=32, trial_block=64,
-                                            variant=variant)
-        got = np.asarray(run())                      # warm + validate
-        ok = bool(np.allclose(got, want, rtol=1e-5, atol=1e-3))
-        t = _best_of(run, n=2)
-        out.append((f"pipeline/agg_variant_{variant}_interp", t * 1e6,
-                    f"matches_ref={ok};T={T};K={K};cat={cat}"))
-    return out
-
-
 _MESH_CHILD = r'''
 import dataclasses, json, os, sys, time
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -783,5 +739,4 @@ def bench_serving_archs() -> List[Row]:
 
 ALL = [bench_pipeline_overlap, bench_serving_overlap,
        bench_serving_continuous, bench_serving_prefix_sharing,
-       bench_paged_attention, bench_kernel_variants, bench_serving_mesh,
-       bench_serving_archs]
+       bench_paged_attention, bench_serving_mesh, bench_serving_archs]

@@ -413,7 +413,6 @@ def risk_phase(*, reduced: bool = False, trials: Optional[int] = RISK_TRIALS,
                            np.maximum(np.abs(want), 1e-30)))
         report(f"risk-{mode}", clock, devices=1, tenants=tenants,
                kernel="pallas" if kops.pallas_enabled() else "jnp",
-               variant=kops.aggregate_variant(),
                run_wall_s=f"{rep.wall_s:.3f}",
                trials_per_s=f"{tables.num_trials / rep.wall_s:.1f}",
                max_rel_err_vs_ref=err, ylt_mean=float(rep.ylt.mean()))

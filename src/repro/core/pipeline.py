@@ -3,11 +3,9 @@
 The simulator in :mod:`repro.core.simulator` *models* the paper's winning
 schedule: with sequential transfers, tenant k+1's host->device staging rides
 the link while tenant k's compute occupies its pdev, so the makespan is
-``max(transfer chain, compute chains)`` instead of their sum.  Before this
-module existed, the executable path did not honour that contract — it staged
-every tenant chunk (blocking per chunk) and only then dispatched compute, so
-the measured wall time was ``sum(transfers) + compute`` and the simulator's
-predicted overlap never materialised.
+``max(transfer chain, compute chains)`` instead of their sum.  A schedule
+that staged every tenant chunk before dispatching any compute would take
+``sum(transfers) + compute`` and never show that overlap.
 
 :class:`PipelineExecutor` is the executable counterpart of the simulated
 schedule — the **simulator-vs-executable overlap contract**:
@@ -38,9 +36,9 @@ realised-overlap signal used by :meth:`PipelineReport.overlaps` —
     ``compute_start(k) <= transfer_start(k+1) < compute_end(k)``
 
 (transfer k+1 began inside compute k's execution window) — is falsifiable in
-both directions: a blocking stage-everything schedule fails the left
-inequality (every transfer precedes every compute; this rejection is
-structural, independent of timing noise), and a dispatch whose compute
+both directions: a schedule that staged everything before any compute
+would fail the left inequality (every transfer would precede every compute;
+this rejection is structural, independent of timing noise), and a dispatch whose compute
 drained before the next chunk was staged fails the right one.  One
 measurement caveat on the right inequality: ``compute_end`` is stamped at
 waiter-thread wakeup, so gaps shorter than a thread wakeup (~tens of µs)
@@ -186,9 +184,8 @@ class PipelineExecutor:
     """Event-driven executor: stage chunk k+1 while chunk k computes.
 
     The executor owns a :class:`StagingEngine` (for placement + the staging
-    log) but drives its non-blocking ``put``/``wait`` primitives instead of
-    the stage-everything entry point, interleaving compute dispatch with the
-    transfer chain.  Spans: ``pipeline.start`` around starting the waiter
+    log) and drives its non-blocking ``put`` and blocking ``wait``,
+    interleaving compute dispatch with the transfer chain.  Spans: ``pipeline.start`` around starting the waiter
     threads, ``pipeline.dispatch`` around each compute call,
     ``pipeline.drain`` while the host waits for the last outputs.
     """

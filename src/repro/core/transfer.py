@@ -11,11 +11,10 @@ Two modes:
 the already-dispatched tenant's compute.  The engine records per-chunk wall
 times for the benchmark harness.
 
-The engine exposes two levels of API: non-blocking :meth:`StagingEngine.put`
-/ :meth:`StagingEngine.wait` primitives that the overlapped executor in
+The engine's API is the non-blocking :meth:`StagingEngine.put` and the
+blocking :meth:`StagingEngine.wait`, which the overlapped executor in
 :mod:`repro.core.pipeline` interleaves with compute dispatch (the paper's
-winning schedule), and the stage-everything :meth:`StagingEngine.stage`
-entry point (the pre-pipeline blocking schedule, kept for A/B benchmarks).
+winning schedule).
 
 Spans: ``transfer.put`` around enqueueing a chunk, ``transfer.wait``
 around blocking until it is device-resident; ``transfer.stage`` is the
@@ -98,36 +97,6 @@ class StagingEngine:
             self.tel.count("transfer.chunks")
         return chunk
 
-    def stage(self, tasks: Sequence[TenantTask],
-              chunk_of: Callable[[TenantTask], Any],
-              block: bool = False) -> List[StagedChunk]:
-        """Stage every tenant chunk per the configured mode.
-
-        ``chunk_of(task)`` returns the host pytree for that tenant.  In
-        sequential mode each chunk blocks until on-device before the next is
-        enqueued (full-bandwidth transfers); concurrent mode enqueues all and
-        only then (optionally) waits.
-
-        This is the *stage-everything* entry point (the pre-pipeline blocking
-        path, kept for A/B benchmarking); the overlapped executor in
-        :mod:`repro.core.pipeline` drives :meth:`put`/:meth:`wait` directly so
-        compute dispatch can interleave with staging.
-        """
-        t0 = time.perf_counter()
-        out: List[StagedChunk] = []
-        if self.mode == "sequential":
-            for t in tasks:
-                c = self.put(t, chunk_of(t), t0)
-                self.wait(c, t0)
-                out.append(c)
-        else:
-            for t in tasks:
-                out.append(self.put(t, chunk_of(t), t0))
-            if block:
-                for c in out:
-                    self.wait(c, t0)
-        return out
-
 
 @dataclasses.dataclass
 class MeshStagedChunk:
@@ -199,7 +168,8 @@ def reorder_for_stragglers(tasks: Sequence[TenantTask],
                            last_step_times: Optional[Dict[int, float]],
                            ) -> List[TenantTask]:
     """Straggler mitigation: stage the slowest tenant of the previous step
-    first so its data is ready earliest (DESIGN.md §7)."""
+    first so its data is ready earliest.  This is the staging order
+    :meth:`repro.core.pipeline.PipelineExecutor.run` follows."""
     if not last_step_times:
         return list(tasks)
     return sorted(tasks, key=lambda t: -last_step_times.get(t.vdev, 0.0))

@@ -4,8 +4,8 @@ TPU adaptation of the paper's GPU kernel.  The GPU version assigns one thread
 per trial and reads the ELT direct-access tables from global memory with
 per-thread random access, chunking the event axis through shared memory.  A
 2,000,000-event catalog of 15 ELTs does not fit the TPU's VMEM, and Mosaic
-has no general vector gather, so the lookup strategies below keep the table
-in a form the chip can address.
+has no general vector gather, so the lookup below keeps the table in a form
+the chip can address.
 
 A call prices one layer, or a portfolio of L layers over the same ELTs
 (a reinsurance tower): occurrence terms ``(M,)`` and scalar aggregate terms
@@ -13,52 +13,44 @@ give a ``(T,)`` year-loss table (YLT), terms ``(L, M)`` and ``(L,)`` a
 ``(T, L)`` one.  A layer that does not cover an ELT has a zero occurrence
 limit on it.
 
-* ``gather`` (default) — one lookup per event.  The occurrence terms are
-  per ELT and a layer adds an event's clipped ELT losses, so every call
-  first combines them per catalog row and layer, ``f[e, l] = sum_m
-  min(max(elt[e, m] - OccR_lm, 0), OccL_lm)``, from the ELT and terms it
-  is given (nothing derived from them outlives the call), and looks each
-  event up in ``f`` in one of two ways chosen by shape
-  (:func:`lookup_path`):
+The lookup, ``gather``, reads one combined loss per event.  The occurrence
+terms are per ELT and a layer adds an event's clipped ELT losses, so every
+call first combines them per catalog row and layer, ``f[e, l] = sum_m
+min(max(elt[e, m] - OccR_lm, 0), OccL_lm)``, from the ELT and terms it is
+given (nothing derived from them outlives the call), and looks each event
+up in ``f`` in one of two ways chosen by shape (:func:`lookup_path`):
 
-  - ``vmem`` — where ``f``, laid out lane-dense, fits
-    :data:`LOOKUP_VMEM_BUDGET` (the paper's 2,000,001 rows do, for one
-    layer in 8 MB and for eight in 64 MiB), it is fetched into VMEM once a
-    call, single-buffered.  Grid = (trial_blocks, event_chunks), a
-    trial's whole event ids a step where they fit
-    :data:`LOOKUP_SMEM_BUDGET`.  The ids reach SMEM in rows of 128 events
-    (of K where K is under 128), the (8, 128) tiles that (T, K) has in
-    HBM, so no copy makes them; the kernel walks a trial's rows and
-    unrolls each row, so an id's SMEM address is its row's plus a
-    constant.  For each event it loads the id, loads the event's row of
-    the table with one dynamic sublane load and adds the event's lanes
-    into the trial's lane accumulator (the id broadcast over the lanes,
-    its slot compared with a lane iota, select, add).  Besides the id's
-    load, an event takes three scalar operations (the id's address, the
-    table row, the row's address); the rest is vector work, and the adds
-    into one accumulator are the chain that bounds the loop.  No DMA per
-    event.  One layer's table is (rows / 128, 128): row ``id >> 7``, lane
-    ``id & 127``.  A tower's layers are padded to a power of two, Lp, and
-    interleaved so that one load brings every layer of an event: a (R,
-    128) table, R a power of two of at least rows / G with G = 128 / Lp,
-    catalog row ``id`` at row ``id & (R - 1)``, lanes ``(id >> log2 R) *
-    Lp + l`` for its layers ``l``
-    (:func:`_tower_table`; built by a 2-D transpose of the layer-major
-    combine).  The last chunk adds the accumulator's G lane groups into
-    the trial's Lp layer losses, by rolls.
-  - ``xla`` — for tables too large for that budget: XLA's gather of the
-    same ``f`` from HBM, then the aggregate terms.
+* ``vmem`` — where ``f``, laid out lane-dense, fits
+  :data:`LOOKUP_VMEM_BUDGET` (the paper's 2,000,001 rows do, for one
+  layer in 8 MB and for eight in 64 MiB), it is fetched into VMEM once a
+  call, single-buffered.  Grid = (trial_blocks, event_chunks), a
+  trial's whole event ids a step where they fit
+  :data:`LOOKUP_SMEM_BUDGET`.  The ids reach SMEM in rows of 128 events
+  (of K where K is under 128), the (8, 128) tiles that (T, K) has in
+  HBM, so no copy makes them; the kernel walks a trial's rows and
+  unrolls each row, so an id's SMEM address is its row's plus a
+  constant.  For each event it loads the id, loads the event's row of
+  the table with one dynamic sublane load and adds the event's lanes
+  into the trial's lane accumulator (the id broadcast over the lanes,
+  its slot compared with a lane iota, select, add).  Besides the id's
+  load, an event takes three scalar operations (the id's address, the
+  table row, the row's address); the rest is vector work, and the adds
+  into one accumulator are the chain that bounds the loop.  No DMA per
+  event.  One layer's table is (rows / 128, 128): row ``id >> 7``, lane
+  ``id & 127``.  A tower's layers are padded to a power of two, Lp, and
+  interleaved so that one load brings every layer of an event: a (R,
+  128) table, R a power of two of at least rows / G with G = 128 / Lp,
+  catalog row ``id`` at row ``id & (R - 1)``, lanes ``(id >> log2 R) *
+  Lp + l`` for its layers ``l``
+  (:func:`_tower_table`; built by a 2-D transpose of the layer-major
+  combine).  The last chunk adds the accumulator's G lane groups into
+  the trial's Lp layer losses, by rolls.
+* ``xla`` — for tables too large for that budget: XLA's gather of the
+  same ``f`` from HBM, then the aggregate terms.
 
-* ``onehot`` — gather-free: the ELT is transposed to (M, catalog) and tiled
-  along the catalog in lane-aligned tiles; each trial's event ids become a
-  (rows_tile, C) one-hot matrix that the MXU multiplies by the tile at
-  ``Precision.HIGHEST`` (exact: one 1.0 per column).  Every event is
-  compared against every catalog row, so the work grows with the catalog;
-  it suits small catalogs only.  A tower runs it once a layer.
-
-Both kernels accumulate the YLT block across event chunks (revisiting
-output) and apply each layer's aggregate terms on the last visit.  They are
-validated in interpret mode against
+The ``vmem`` kernel accumulates the YLT block across event chunks and
+applies each layer's aggregate terms on the last visit.  Both paths are
+validated on the CPU (the kernel in interpret mode) against
 :func:`repro.kernels.ref.aggregate_loss_chunked_ref`
 (tests/test_kernels_aggregate.py) and compiled for a described v5e at the
 paper's widths (tests/test_tpu_compile.py).
@@ -66,7 +58,6 @@ paper's widths (tests/test_tpu_compile.py).
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
@@ -74,13 +65,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import AGG_VARIANTS as VARIANTS, resolve_interpret
+from repro.kernels.ops import resolve_interpret
 
 LANES = 128
-# VMEM budget for one onehot catalog tile: the double-buffered (Mp, rows)
-# ELT tile plus the (rows, C) one-hot and row-id planes it is compared with
-ONEHOT_VMEM_BUDGET = 8 << 20
-# VMEM budget for the gather variant's combined occurrence losses, one float32
+# VMEM budget for the lookup's combined occurrence losses, one float32
 # per catalog row and padded layer: within it the lookup is a VMEM load (the
 # ``vmem`` path), beyond it XLA's gather from HBM (``xla``).  A v5e has
 # 128 MiB of VMEM; the call asks for the table plus 8 MiB
@@ -121,11 +109,10 @@ def table_rows(rows: int, layers: int = 1) -> int:
 
 
 def lookup_path(rows: int, layers: int = 1) -> str:
-    """The ``gather`` variant's lookup for a catalog of ``rows`` rows (pad
-    row 0 included) priced for ``layers`` layers: ``"vmem"`` where the
-    combined occurrence losses, laid out lane-dense
-    (:func:`table_rows` x 128 float32), fit :data:`LOOKUP_VMEM_BUDGET`,
-    else ``"xla"``."""
+    """The lookup's path for a catalog of ``rows`` rows (pad row 0
+    included) priced for ``layers`` layers: ``"vmem"`` where the combined
+    occurrence losses, laid out lane-dense (:func:`table_rows` x 128
+    float32), fit :data:`LOOKUP_VMEM_BUDGET`, else ``"xla"``."""
     vmem_bytes = 4 * LANES * table_rows(rows, layers)
     return "vmem" if vmem_bytes <= LOOKUP_VMEM_BUDGET else "xla"
 
@@ -210,7 +197,7 @@ def _vmem_kernel(ids_ref, f_ref, agg_ref, out_ref, acc_ref, *,
 
 def _vmem_lookup(ids, f, agg, *, trial_block: Optional[int],
                  interpret: bool):
-    """The ``vmem`` path of the gather variant: ``ids`` is (T, K); ``f``
+    """The ``vmem`` path of the lookup: ``ids`` is (T, K); ``f``
     the (rows,) combined occurrence losses and ``agg`` the (2,) aggregate
     terms of one layer, or ``f`` (L, rows) and ``agg`` (2, L) for L layers.
     Returns the (T,) or (T, L) YLT."""
@@ -264,65 +251,20 @@ def _vmem_lookup(ids, f, agg, *, trial_block: Optional[int],
     return out[:T, :layers] if layered else out[:T, 0]
 
 
-def _onehot_kernel(ids_ref, elt_ref, ret_ref, lim_ref, agg_ref, out_ref, *,
-                   rows_tile: int, n_cat: int, n_chunks: int):
-    r = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when((r == 0) & (j == 0))
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    tb, c = ids_ref.shape
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows_tile, c), 0) \
-        + r * rows_tile
-    elt = elt_ref[...]                                   # (Mp, rows_tile)
-    for t in range(tb):
-        # ids outside this tile match no row: a zero column, and with
-        # occ_ret >= 0 a zero occurrence loss
-        onehot = (row_ids == ids_ref[pl.ds(t, 1), :]).astype(jnp.float32)
-        g = jnp.dot(elt, onehot, precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)  # (Mp, C)
-        occ = jnp.minimum(jnp.clip(g - ret_ref[...], 0.0, None),
-                          lim_ref[...])
-        out_ref[pl.ds(t, 1), :] += occ.sum(axis=(0, 1), keepdims=True)
-
-    @pl.when((r == n_cat - 1) & (j == n_chunks - 1))
-    def _agg():
-        _aggregate_terms(out_ref, agg_ref)
-
-
-def onehot_rows_tile(num_elts: int, chunk: int) -> int:
-    """Catalog rows per onehot tile, from the VMEM footprint as laid out:
-    per row, the double-buffered ELT column (``num_elts`` rounded up to 8
-    sublanes) plus one one-hot and one row-id entry per event of the chunk
-    (``chunk`` rounded up to 128 lanes).  A multiple of 128 (the tile's
-    lane axis)."""
-    per_row = 4 * (2 * _round_up(num_elts, 8) + 2 * _round_up(chunk, LANES))
-    return max(LANES, ONEHOT_VMEM_BUDGET // per_row // LANES * LANES)
-
-
 def aggregate_loss_pallas(event_ids, elt_losses, occ_ret, occ_lim, agg_ret,
-                          agg_lim, *, chunk: int = 128,
-                          trial_block: Optional[int] = None,
-                          rows_tile: Optional[int] = None,
-                          interpret: Optional[bool] = None,
-                          variant: str = "gather"):
+                          agg_lim, *, trial_block: Optional[int] = None,
+                          interpret: Optional[bool] = None):
     """Drop-in equivalent of kernels.ref.aggregate_loss_chunked_ref: one
     layer's terms, ``(M,)`` and scalars, give the ``(T,)`` YLT; L layers'
     terms, ``(L, M)`` and ``(L,)``, the ``(T, L)`` YLT.
 
-    ``variant``: "gather" (one lookup per event of the combined occurrence
-    losses, every layer at once: from VMEM, or by XLA's gather where the
-    table is too large, see :func:`lookup_path`) or "onehot" (one-hot x
-    catalog-tile matmul on the MXU, a layer at a time; ``rows_tile``
-    overrides its tile, rounded up to a multiple of 128).  ``trial_block``
-    overrides the trials a grid step prices (8 on the onehot path, up to
-    :data:`LOOKUP_TRIAL_BLOCK` on the VMEM path).  ``interpret`` defaults
-    to the platform's choice (:func:`repro.kernels.ops.resolve_interpret`).
+    Every event is looked up once in the combined occurrence losses, every
+    layer at once: from VMEM, or by XLA's gather where the table is too
+    large (:func:`lookup_path`).  ``trial_block`` overrides the trials a
+    grid step of the VMEM lookup prices (up to
+    :data:`LOOKUP_TRIAL_BLOCK`).  ``interpret`` defaults to the platform's
+    choice (:func:`repro.kernels.ops.resolve_interpret`).
     """
-    if variant not in VARIANTS:
-        raise ValueError(f"variant {variant!r}: must be one of {VARIANTS}")
     interpret = resolve_interpret(interpret)
     if jnp.ndim(occ_ret) == 2:
         layers = occ_ret.shape[0]
@@ -330,61 +272,22 @@ def aggregate_loss_pallas(event_ids, elt_losses, occ_ret, occ_lim, agg_ret,
             raise ValueError(f"{layers} layers' occurrence terms need "
                              f"({layers},) aggregate terms, not "
                              f"{jnp.shape(agg_ret)} and {jnp.shape(agg_lim)}")
-        if layers == 1 or variant == "onehot":
-            # one layer is the one-layer program; onehot prices a layer a call
-            one = functools.partial(
-                aggregate_loss_pallas, event_ids, elt_losses, chunk=chunk,
-                trial_block=trial_block, rows_tile=rows_tile,
-                interpret=interpret, variant=variant)
-            return jnp.stack([one(occ_ret[l], occ_lim[l], agg_ret[l],
-                                  agg_lim[l]) for l in range(layers)], axis=1)
-    T, K = event_ids.shape
-    rows, M = elt_losses.shape
-    chunk = min(chunk, K)
-    while K % chunk:
-        chunk //= 2
+        if layers == 1:     # one layer is the one-layer program
+            y = aggregate_loss_pallas(
+                event_ids, elt_losses, occ_ret[0], occ_lim[0], agg_ret[0],
+                agg_lim[0], trial_block=trial_block, interpret=interpret)
+            return y[:, None]
+    rows = elt_losses.shape[0]
     ids = event_ids.astype(jnp.int32)
     elt = elt_losses.astype(jnp.float32)
     occ_ret = occ_ret.astype(jnp.float32)
     occ_lim = occ_lim.astype(jnp.float32)
     agg = jnp.stack([jnp.asarray(agg_ret, jnp.float32),
                      jnp.asarray(agg_lim, jnp.float32)])
-    if variant == "gather":
-        f = _combined_losses(elt, occ_ret, occ_lim)
-        layers = f.shape[0] if f.ndim == 2 else 1
-        if lookup_path(rows, layers) == "vmem":
-            return _vmem_lookup(ids, f, agg, trial_block=trial_block,
-                                interpret=interpret)
-        y = f.T[ids].sum(axis=1)
-        return jnp.minimum(jnp.clip(y - agg[0], 0.0, None), agg[1])
-
-    # the (tb, 1) YLT block needs tb % 8 == 0 (or the whole axis): pad the
-    # trial axis with all-pad trials, sliced off below
-    tb = min(_round_up(trial_block or 8, 8), _round_up(T, 8))
-    T_pad = _round_up(T, tb)
-    if T_pad != T:
-        ids = jnp.pad(ids, ((0, T_pad - T), (0, 0)))
-    n_chunks = K // chunk
-    Mp = _round_up(M, 8)
-    if rows_tile is None:
-        rows_tile = onehot_rows_tile(M, chunk)
-    rows_tile = min(_round_up(rows_tile, LANES), _round_up(rows, LANES))
-    n_cat = math.ceil(rows / rows_tile)
-    elt_t = jnp.pad(elt, ((0, n_cat * rows_tile - rows), (0, Mp - M))).T
-    col = lambda a: jnp.pad(a, (0, Mp - M)).reshape(Mp, 1)
-    out = pl.pallas_call(
-        functools.partial(_onehot_kernel, rows_tile=rows_tile, n_cat=n_cat,
-                          n_chunks=n_chunks),
-        grid=(n_cat, T_pad // tb, n_chunks),
-        in_specs=[
-            pl.BlockSpec((tb, chunk), lambda r, i, j: (i, j)),
-            pl.BlockSpec((Mp, rows_tile), lambda r, i, j: (0, r)),
-            pl.BlockSpec((Mp, 1), lambda r, i, j: (0, 0)),
-            pl.BlockSpec((Mp, 1), lambda r, i, j: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((tb, 1), lambda r, i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((T_pad, 1), jnp.float32),
-        interpret=interpret,
-    )(ids, elt_t, col(occ_ret), col(occ_lim), agg)
-    return out[:T, 0]
+    f = _combined_losses(elt, occ_ret, occ_lim)
+    layers = f.shape[0] if f.ndim == 2 else 1
+    if lookup_path(rows, layers) == "vmem":
+        return _vmem_lookup(ids, f, agg, trial_block=trial_block,
+                            interpret=interpret)
+    y = f.T[ids].sum(axis=1)
+    return jnp.minimum(jnp.clip(y - agg[0], 0.0, None), agg[1])

@@ -2,15 +2,13 @@
 decides how Pallas kernels run on this process's platform.
 
 On a TPU the Pallas kernels run compiled and are the default path; elsewhere
-the pure-jnp references run unless ``use_pallas(True)`` (or
-REPRO_USE_PALLAS=1) routes to the kernels, which then execute in Pallas
-interpret mode.  Interpret mode is reachable on the CPU only: asking for it
+the pure-jnp references run unless ``use_pallas(True)`` routes to the
+kernels, which then execute in Pallas interpret mode.  Interpret mode is reachable on the CPU only: asking for it
 on a TPU raises.  The model/risk stacks only ever import from here.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -46,20 +44,6 @@ def _ssd_pallas_vjp(chunk: int, interpret: bool):
     f.defvjp(fwd, bwd)
     return f
 
-# single source of truth for aggregate_loss lookup strategies
-AGG_VARIANTS = ("gather", "onehot")
-
-
-def _env_agg_variant() -> str:
-    """Fail fast (at import) on a misconfigured REPRO_AGG_VARIANT instead of
-    deferring to an error — or, under ``python -O``, a silent fallback —
-    deep inside the Pallas dispatch."""
-    v = os.environ.get("REPRO_AGG_VARIANT", "gather")
-    if v not in AGG_VARIANTS:
-        raise ValueError(
-            f"REPRO_AGG_VARIANT={v!r}: must be one of {AGG_VARIANTS}")
-    return v
-
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -86,15 +70,8 @@ def default_kernel_backend() -> str:
     return "pallas" if on_tpu() else "jnp"
 
 
-def _env_pallas() -> Optional[bool]:
-    v = os.environ.get("REPRO_USE_PALLAS")
-    return None if v is None else v == "1"
-
-
 # "pallas"/"interpret" hold an explicit choice, or None for the platform's
-_STATE = {"pallas": _env_pallas(),
-          "interpret": None,
-          "agg_variant": _env_agg_variant()}
+_STATE = {"pallas": None, "interpret": None}
 
 
 def use_pallas(on: Optional[bool], interpret: Optional[bool] = None) -> None:
@@ -108,37 +85,18 @@ def pallas_enabled() -> bool:
     return on_tpu() if _STATE["pallas"] is None else _STATE["pallas"]
 
 
-def use_aggregate_variant(name: str) -> None:
-    """Select the aggregate_loss Pallas lookup strategy: "gather" (one
-    lookup per event, from VMEM or by XLA's gather, chosen by the catalog's
-    size: :func:`aggregate_lookup`) or "onehot" (gather-free one-hot x ELT
-    matmul on the MXU).  Also settable via REPRO_AGG_VARIANT.  No effect on
-    the jnp reference path, which is lookup-strategy-free."""
-    if name not in AGG_VARIANTS:
-        raise ValueError(f"variant {name!r}: must be one of {AGG_VARIANTS}")
-    _STATE["agg_variant"] = name
-
-
-def aggregate_variant() -> str:
-    return _STATE["agg_variant"]
-
-
-def aggregate_lookup(rows: int, variant: Optional[str] = None,
-                     layers: Optional[int] = None) -> str:
+def aggregate_lookup(rows: int, layers: Optional[int] = None) -> str:
     """The lookup :func:`aggregate_loss` runs for a catalog of ``rows`` ELT
-    rows, with ``variant`` as it would be given: "jnp" (the reference),
-    "onehot", or the gather variant's "vmem" (combined occurrence losses
-    resident in VMEM, where they fit its budget) or "xla" (XLA's gather of
-    them from HBM).  A tower of ``layers`` layers (terms with a layer axis)
-    names its layer count too, as in "vmem.layers8"; one layer given
-    without the axis (``None``) does not."""
+    rows: "jnp" (the reference), or the Pallas kernel's "vmem" (combined
+    occurrence losses resident in VMEM, where they fit its budget) or "xla"
+    (XLA's gather of them from HBM).  A tower of ``layers`` layers (terms
+    with a layer axis) names its layer count too, as in "vmem.layers8"; one
+    layer given without the axis (``None``) does not."""
     if not pallas_enabled():
         path = "jnp"
     else:
-        path = variant or _STATE["agg_variant"]
-        if path == "gather":
-            from repro.kernels import aggregate_loss as _agg
-            path = _agg.lookup_path(rows, layers or 1)
+        from repro.kernels import aggregate_loss as _agg
+        path = _agg.lookup_path(rows, layers or 1)
     return path if layers is None else f"{path}.layers{layers}"
 
 
@@ -172,18 +130,17 @@ def ssd_decode_step(state, x_t, dt_t, a_t, B_t, C_t):
 
 
 def aggregate_loss(event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,
-                   chunk: int = 128, variant: Optional[str] = None):
+                   chunk: int = 128):
     """Year-loss per trial (paper Algorithm 3): ``(T,)`` for one layer's
     terms (``occ_ret``/``occ_lim`` ``(M,)``, scalar aggregate terms),
     ``(T, L)`` for a portfolio of L layers' (``(L, M)`` and ``(L,)``).
 
     Pads the event axis to a chunk multiple with event id 0 — the pad event
-    row of every ELT is zero by contract, so pads contribute no loss.
-    ``variant`` overrides the configured Pallas lookup strategy (see
-    :func:`use_aggregate_variant`); ignored on the jnp reference path.  With
-    "gather", a catalog whose combined occurrence losses fit the kernel's
-    VMEM budget (the paper's 2,000,001 rows do) is looked up in VMEM, a
-    larger one by XLA's gather; :func:`aggregate_lookup` says which runs."""
+    row of every ELT is zero by contract, so pads contribute no loss.  On
+    the Pallas path a catalog whose combined occurrence losses fit the
+    kernel's VMEM budget (the paper's 2,000,001 rows do) is looked up in
+    VMEM, a larger one by XLA's gather; :func:`aggregate_lookup` says which
+    runs."""
     K = event_ids.shape[1]
     chunk = min(chunk, K)
     pad = (-K) % chunk
@@ -193,7 +150,6 @@ def aggregate_loss(event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,
         from repro.kernels import aggregate_loss as _agg
         return _agg.aggregate_loss_pallas(
             event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim,
-            chunk=chunk, interpret=_STATE["interpret"],
-            variant=variant or _STATE["agg_variant"])
+            interpret=_STATE["interpret"])
     return _ref.aggregate_loss_chunked_ref(
         event_ids, elt_losses, occ_ret, occ_lim, agg_ret, agg_lim, chunk=chunk)

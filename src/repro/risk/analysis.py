@@ -13,8 +13,7 @@ Three execution paths over the same numerics (kernels/ops.aggregate_loss):
   :class:`repro.core.pipeline.PipelineExecutor`: tenant k's jitted compute is
   dispatched the moment its chunk is device-resident, so tenant k+1's staging
   overlaps tenant k's compute (the paper's winning schedule, Fig 13) and each
-  pdev's execution stream serialises its tenants.  ``overlapped=False`` keeps
-  the old stage-everything-then-compute path for A/B benchmarking.
+  pdev's execution stream serialises its tenants.
 * ``make_sharded_step`` — pjit over a mesh (trials sharded over every mesh
   axis) for the production dry-run; this is the "beyond-paper" scale-out.
 
@@ -41,7 +40,7 @@ executor's ``pipeline.start``, ``risk.slice`` / ``transfer.put`` /
 which reads the results back).  Each dispatched step counts
 ``risk.lookup.<path>``, the lookup its program was traced with
 (``kernels.ops.aggregate_lookup``: ``vmem`` or ``xla`` on the Pallas
-gather, ``vmem.layers8`` for a tower of 8 layers).
+path, ``vmem.layers8`` for a tower of 8 layers).
 """
 from __future__ import annotations
 
@@ -57,7 +56,6 @@ import numpy as np
 from repro.configs.risk_app import RiskAppConfig
 from repro.core.pipeline import PipelineExecutor, TenantTimeline
 from repro.core.tenancy import TenancyConfig, TenantTask, VirtualDevicePool
-from repro.core.transfer import StagingEngine, reorder_for_stragglers
 from repro.kernels import ops as kops
 from repro.obs.telemetry import Telemetry, get_telemetry
 from repro.risk.tables import RiskTables
@@ -72,7 +70,7 @@ class RunReport:
     wall_s: float
     per_tenant_s: Dict[int, float]
     staging_log: List[Dict[str, float]]
-    timeline: Optional[List[TenantTimeline]] = None
+    timeline: List[TenantTimeline]
 
 
 def _loss_args(tables: RiskTables):
@@ -196,16 +194,11 @@ class AggregateRiskAnalysis:
 
     # ------------------------------------------------------------------
     def run_tenant_chunked(self, tables: RiskTables,
-                           straggler_hist: Optional[Dict[int, float]] = None,
-                           overlapped: bool = True) -> RunReport:
-        """Multi-tenant execution per the tenancy plan.
-
-        ``overlapped=True`` (default) runs the event-driven pipeline —
-        compute(k) dispatches as soon as chunk k lands, staging of chunk k+1
-        overlaps it.  ``overlapped=False`` is the legacy blocking schedule
-        (stage *all* tenants, then dispatch compute), kept only so the
-        benchmark harness can measure what the overlap buys.
-        """
+                           straggler_hist: Optional[Dict[int, float]] = None
+                           ) -> RunReport:
+        """Multi-tenant execution per the tenancy plan, on the event-driven
+        pipeline: compute(k) dispatches as soon as chunk k lands, and
+        staging of chunk k+1 overlaps it."""
         tel = self.tel
         with tel.span("risk.analysis", layers=tables.num_layers):
             t_start = time.perf_counter()
@@ -233,33 +226,14 @@ class AggregateRiskAnalysis:
                 return out
 
             ylt = np.zeros(tables.ylt_shape, np.float32)
-            if overlapped:
-                ex = PipelineExecutor(self.pool, telemetry=tel)
-                rep = ex.run(tasks, stage_fn, compute_fn, straggler_hist)
-                with tel.span("risk.gather"):
-                    for t in tasks:
-                        ylt[t.start:t.stop] = \
-                            np.asarray(rep.results[t.vdev])[:t.size]
-                return RunReport(ylt, time.perf_counter() - t_start,
-                                 rep.per_tenant_s(), ex.engine.log,
-                                 rep.timeline)
-
-            # legacy blocking path: stage everything, then compute
-            order = reorder_for_stragglers(tasks, straggler_hist)
-            engine = StagingEngine(self.pool, telemetry=tel)
-            staged = engine.stage(order, stage_fn, block=True)
-            per_tenant: Dict[int, float] = {}
-            results = []
-            for sc in staged:     # dispatch all (async) — pdevs serialise
-                t0 = time.perf_counter()
-                out = compute_fn(sc.task, sc.arrays)
-                results.append((sc.task, out, t0))
-            for task, out, t0 in results:
-                out.block_until_ready()
-                per_tenant[task.vdev] = time.perf_counter() - t0
-                ylt[task.start:task.stop] = np.asarray(out)[:task.size]
-            return RunReport(ylt, time.perf_counter() - t_start, per_tenant,
-                             engine.log)
+            ex = PipelineExecutor(self.pool, telemetry=tel)
+            rep = ex.run(tasks, stage_fn, compute_fn, straggler_hist)
+            with tel.span("risk.gather"):
+                for t in tasks:
+                    ylt[t.start:t.stop] = \
+                        np.asarray(rep.results[t.vdev])[:t.size]
+            return RunReport(ylt, time.perf_counter() - t_start,
+                             rep.per_tenant_s(), ex.engine.log, rep.timeline)
 
     # ------------------------------------------------------------------
     def make_sharded_step(self, mesh, chunk: Optional[int] = None):

@@ -45,14 +45,8 @@ def test_mesh_serving_phase_reduced(smoke):
     assert out["token_exact"]
 
 
-@pytest.mark.parametrize("variant", kops.AGG_VARIANTS)
-def test_risk_phase_reduced(smoke, pallas_on, variant):
-    prev = kops.aggregate_variant()
-    kops.use_aggregate_variant(variant)
-    try:
-        errs = smoke.risk_phase(reduced=True, trials=None)
-    finally:
-        kops.use_aggregate_variant(prev)
+def test_risk_phase_reduced(smoke, pallas_on):
+    errs = smoke.risk_phase(reduced=True, trials=None)
     assert set(errs) == {"sequential", "concurrent"}
     assert max(errs.values()) <= smoke.YLT_RTOL
 

@@ -20,22 +20,27 @@ def _case(rng, T, K, M, cat):
 
 
 SWEEP = [
-    # T, K, M, cat, chunk, trial_block, rows_tile
-    (64, 32, 3, 512, 16, 32, None),
-    (128, 64, 5, 1000, 32, 64, 256),
-    (32, 16, 1, 100, 8, 8, 64),
-    (256, 128, 15, 4096, 128, 256, 512),
-    (17, 24, 2, 50, 8, 16, None),      # odd trial count
-    (48, 96, 7, 333, 48, 16, 100),     # non-pow2 catalog/tile
+    # T, K, M, cat, chunk (of the oracle), trial_block
+    (64, 32, 3, 512, 16, 32),
+    (128, 64, 5, 1000, 32, 64),
+    (32, 16, 1, 100, 8, 8),
+    (256, 128, 15, 4096, 128, 256),
+    (17, 24, 2, 50, 8, 16),      # odd trial count
+    (48, 96, 7, 333, 48, 16),    # non-pow2 catalog
 ]
 
 
-@pytest.mark.parametrize("variant", ["gather", "onehot"])
-@pytest.mark.parametrize("T,K,M,cat,chunk,tb,rt", SWEEP)
-def test_pallas_matches_oracle(rng, T, K, M, cat, chunk, tb, rt, variant):
+@pytest.mark.parametrize("path", ["vmem", "xla"])
+@pytest.mark.parametrize("T,K,M,cat,chunk,tb", SWEEP)
+def test_pallas_matches_oracle(rng, monkeypatch, T, K, M, cat, chunk, tb,
+                               path):
+    """Both lookups, VMEM and (the budget lowered) XLA's gather, against the
+    oracle."""
     args = _case(rng, T, K, M, cat)
-    got = aggregate_loss_pallas(*args, chunk=chunk, trial_block=tb,
-                                rows_tile=rt, variant=variant)
+    if path == "xla":
+        monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 0)
+    assert agg.lookup_path(cat + 1) == path
+    got = aggregate_loss_pallas(*args, trial_block=tb)
     want = aggregate_loss_chunked_ref(*args, chunk=chunk)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-3)
@@ -56,7 +61,7 @@ def test_vmem_lookup_edge_rows(rng, T, K, M, cat, chunk):
     ids, *rest = _case(rng, T, K, M, cat)
     ids = ids.at[:, 0].set(0).at[:, 1].set(cat).at[0].set(cat)
     args = (ids, *rest[:3], np.float32(0), np.float32(1e9))
-    got = aggregate_loss_pallas(*args, chunk=chunk)
+    got = aggregate_loss_pallas(*args)
     want = aggregate_loss_chunked_ref(*args, chunk=chunk)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-3)
@@ -70,28 +75,28 @@ def test_xla_fallback_beyond_budget(rng, monkeypatch):
     monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 4 * 700)
     assert agg.lookup_path(701) == "xla"
     assert agg.lookup_path(700 - 128) == "vmem"
-    got = aggregate_loss_pallas(*args, chunk=32, trial_block=8)
+    got = aggregate_loss_pallas(*args, trial_block=8)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-3)
 
 
-def test_aggregate_lookup_follows_variant(monkeypatch):
-    """kernels.ops names the lookup aggregate_loss runs, a ``variant=``
-    override included."""
+def test_aggregate_lookup_follows_shape(monkeypatch):
+    """kernels.ops names the lookup aggregate_loss runs: the reference off
+    the Pallas path, else the kernel's choice from the table's size."""
     from repro.kernels import ops as kops
-    prev_pallas, prev_variant = kops.pallas_enabled(), kops.aggregate_variant()
+    prev = kops.pallas_enabled()
     try:
         kops.use_pallas(False)
-        assert kops.aggregate_lookup(701, "onehot") == "jnp"
+        assert kops.aggregate_lookup(701) == "jnp"
+        assert kops.aggregate_lookup(701, layers=8) == "jnp.layers8"
         kops.use_pallas(True)
-        kops.use_aggregate_variant("onehot")
-        assert kops.aggregate_lookup(701) == "onehot"
-        assert kops.aggregate_lookup(701, "gather") == "vmem"
+        assert kops.aggregate_lookup(701) == "vmem"
+        assert kops.aggregate_lookup(701, layers=8) == "vmem.layers8"
         monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 4 * 700)
-        assert kops.aggregate_lookup(701, "gather") == "xla"
+        assert kops.aggregate_lookup(701) == "xla"
+        assert kops.aggregate_lookup(701 - 128) == "vmem"
     finally:
-        kops.use_pallas(prev_pallas)
-        kops.use_aggregate_variant(prev_variant)
+        kops.use_pallas(prev)
 
 
 def test_tenant_steps_count_their_lookup():
@@ -120,22 +125,26 @@ def test_tenant_steps_count_their_lookup():
     np.testing.assert_allclose(ylt, want, rtol=1e-5, atol=1e-3)
 
 
-def test_variant_selection_via_ops(rng):
-    """kernels.ops routes the configured variant to the Pallas kernel."""
+def test_ops_routes_to_the_kernel(rng):
+    """kernels.ops pads the event axis to a chunk multiple and runs the
+    Pallas kernel where Pallas is on, the jnp reference where it is off,
+    with the same answers."""
     from repro.kernels import ops as kops
-    args = _case(rng, 32, 16, 2, 128)
-    want = np.asarray(aggregate_loss_chunked_ref(*args, chunk=8))
-    prev_pallas, prev_variant = kops.pallas_enabled(), kops.aggregate_variant()
-    kops.use_pallas(True)
+    args = _case(rng, 32, 20, 2, 128)
+    want = np.asarray(aggregate_loss_chunked_ref(*args, chunk=4))
+    prev = kops.pallas_enabled()
     try:
-        for variant in ("gather", "onehot"):
-            kops.use_aggregate_variant(variant)
-            assert kops.aggregate_variant() == variant
-            got = np.asarray(kops.aggregate_loss(*args, chunk=8))
-            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
+        for pallas in (True, False):
+            kops.use_pallas(pallas)
+            # a new function a route: traces are cached by function
+            step = lambda *a: kops.aggregate_loss(*a, chunk=8)
+            eqns = jax.make_jaxpr(step)(*args).jaxpr.eqns
+            assert any(e.primitive.name == "pallas_call"
+                       for e in eqns) == pallas
+            np.testing.assert_allclose(np.asarray(step(*args)), want,
+                                       rtol=1e-5, atol=1e-3)
     finally:
-        kops.use_pallas(prev_pallas)
-        kops.use_aggregate_variant(prev_variant)
+        kops.use_pallas(prev)
 
 
 def test_chunked_ref_matches_unchunked(rng):
@@ -150,7 +159,7 @@ def test_pad_event_contributes_zero(rng):
     ids = jnp.zeros((8, 16), jnp.int32)        # all pads
     elt = jnp.ones((100, 3), jnp.float32).at[0].set(0.0)
     z = aggregate_loss_pallas(ids, elt, jnp.zeros(3), jnp.full(3, 1e9),
-                              np.float32(0), np.float32(1e9), chunk=16)
+                              np.float32(0), np.float32(1e9))
     np.testing.assert_allclose(np.asarray(z), 0.0)
 
 
@@ -160,16 +169,16 @@ def test_occurrence_and_aggregate_clipping(rng):
     elt = jnp.zeros((3, 1), jnp.float32).at[1, 0].set(10.0)
     y = aggregate_loss_pallas(ids, elt, jnp.asarray([2.0]),
                               jnp.asarray([5.0]), np.float32(1.0),
-                              np.float32(3.0), chunk=1)
+                              np.float32(3.0))
     # aggregate: max(5-1,0)=4, capped at 3
     np.testing.assert_allclose(np.asarray(y), [3.0])
 
 
 def test_int32_vs_int64_ids_and_f32(rng):
     args = list(_case(rng, 32, 32, 3, 128))
-    got32 = aggregate_loss_pallas(*args, chunk=16)
+    got32 = aggregate_loss_pallas(*args)
     args[0] = args[0].astype(jnp.int32)
-    got = aggregate_loss_pallas(*args, chunk=16)
+    got = aggregate_loss_pallas(*args)
     np.testing.assert_allclose(np.asarray(got32), np.asarray(got))
 
 
@@ -202,8 +211,7 @@ def test_tower_matches_oracle(rng, T, K, M, cat, chunk, tb, L):
     a ragged trial count."""
     args = _tower_case(rng, T, K, M, cat, L)
     assert agg.lookup_path(cat + 1, L) == "vmem"
-    got = np.asarray(aggregate_loss_pallas(*args, chunk=chunk,
-                                           trial_block=tb))
+    got = np.asarray(aggregate_loss_pallas(*args, trial_block=tb))
     want = np.asarray(aggregate_loss_chunked_ref(*args, chunk=chunk))
     assert got.shape == want.shape == (T, L)
     assert (want > 0).any(axis=0).all()
@@ -212,30 +220,34 @@ def test_tower_matches_oracle(rng, T, K, M, cat, chunk, tb, L):
     np.testing.assert_allclose(np.asarray(one), want, rtol=1e-5, atol=1e-3)
 
 
-@pytest.mark.parametrize("variant", ["gather", "onehot", "xla"])
-def test_tower_variants_match_oracle(rng, variant, monkeypatch):
-    """The onehot kernel prices a tower a layer at a time, and a table over
-    the budget falls back to XLA's gather of it, with the same answers."""
-    args = _tower_case(rng, 24, 64, 4, 500, 5)
-    if variant == "xla":
+@pytest.mark.parametrize("path,L", [("vmem", 5), ("xla", 5), ("xla", 8)])
+def test_tower_paths_match_oracle(rng, path, L, monkeypatch):
+    """A tower whose layers pad to a power of two prices in VMEM, and a
+    table over the budget falls back to XLA's gather of it, with the same
+    answers."""
+    args = _tower_case(rng, 24, 64, 4, 500, L)
+    if path == "xla":
         monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 4 * 500)
-        assert agg.lookup_path(501, 5) == "xla"
-        variant = "gather"
-    got = aggregate_loss_pallas(*args, chunk=32, variant=variant)
+    assert agg.lookup_path(501, L) == path
+    got = aggregate_loss_pallas(*args)
     want = aggregate_loss_chunked_ref(*args, chunk=32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-3)
 
 
-@pytest.mark.parametrize("pallas", [True, False])
-def test_one_layer_without_the_axis_is_the_one_layer_program(rng, pallas):
+@pytest.mark.parametrize("path", ["jnp", "vmem", "xla"])
+def test_one_layer_without_the_axis_is_the_one_layer_program(rng, path,
+                                                              monkeypatch):
     """``(M,)`` terms give the ``(T,)`` YLT, bit for bit the column that
-    the same terms given as one layer, ``(1, M)``, give."""
+    the same terms given as one layer, ``(1, M)``, give, on each path."""
     from repro.kernels import ops as kops
     ids, elt, ret, lim, agg_ret, agg_lim = _case(rng, 40, 64, 6, 400)
+    if path == "xla":
+        monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 0)
     prev = kops.pallas_enabled()
-    kops.use_pallas(pallas)
+    kops.use_pallas(path != "jnp")
     try:
+        assert kops.aggregate_lookup(401) == path
         flat = np.asarray(kops.aggregate_loss(ids, elt, ret, lim, agg_ret,
                                               agg_lim, chunk=32))
         layered = np.asarray(kops.aggregate_loss(

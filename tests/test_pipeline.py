@@ -49,13 +49,6 @@ def test_overlapped_bit_identical_to_single(cfg, tables, tenants, mode):
     assert rep.timeline is not None and len(rep.timeline) == tenants
 
 
-def test_overlapped_matches_blocking(cfg, tables):
-    ara = AggregateRiskAnalysis(cfg, TenancyConfig(1, 4))
-    a = ara.run_tenant_chunked(tables, overlapped=True)
-    b = ara.run_tenant_chunked(tables, overlapped=False)
-    np.testing.assert_array_equal(a.ylt, b.ylt)
-
-
 def test_ragged_trials_bit_identical(cfg):
     """67 trials over 4 vdevs: uniform padding must not perturb results."""
     t67 = generate(dataclasses.replace(cfg, num_trials=67), seed=3)

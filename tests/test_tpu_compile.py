@@ -3,8 +3,9 @@ widths they run at: the paged decode read and the admission scatter at
 internlm2-1.8b widths, the aggregate-loss kernel at the paper's risk widths
 (1000 events per trial, 15 ELTs, a 2,000,000-event catalog: the VMEM lookup
 for one layer and for a tower of 8, with whole rows of ids a step or, the
-SMEM budget lowered, one row a trial, and XLA's gather with the VMEM budget
-lowered) and the SSD scan at mamba2-2.7b widths.
+SMEM budget lowered, one row a trial, XLA's gather with the VMEM budget
+lowered, and the risk step's entry point in ``kernels.ops``) and the SSD
+scan at mamba2-2.7b widths.
 
 Nothing runs: the TPU compiler checks tiling, VMEM use and lowering, which
 interpret mode on the CPU cannot.  The topology is described inside a
@@ -89,35 +90,57 @@ def test_paged_scatter_compiles(one_chip, no_compile_cache):
              ((L, nb, PAGE, Hkv, D), jnp.bfloat16))
 
 
-def _compile_aggregate_loss(sharding, variant, kernel=True, layers=None):
+def _risk_shapes(K, layers):
+    """The step's arguments at the paper's widths: a tenant's (trials, K)
+    ids, the ELT, and the terms of one layer (``layers`` None) or a tower."""
+    M = RISK.num_elts
+    lay = () if layers is None else (layers,)
+    return (((RISK_TRIALS, K), jnp.int32),
+            ((RISK.event_catalog + 1, M), jnp.float32),
+            (lay + (M,), jnp.float32), (lay + (M,), jnp.float32),
+            (lay, jnp.float32), (lay, jnp.float32))
+
+
+def _compile_aggregate_loss(sharding, kernel=True, layers=None):
     """``layers``: a tower's layer count (terms with a layer axis), or None
     for one layer's terms."""
     K = -(-RISK.events_per_trial // RISK.chunk_events) * RISK.chunk_events
-    M = RISK.num_elts
-    lay = () if layers is None else (layers,)
     compiled = _compile(
-        lambda *a: agg.aggregate_loss_pallas(*a, chunk=RISK.chunk_events,
-                                             interpret=False,
-                                             variant=variant),
-        sharding,
-        ((RISK_TRIALS, K), jnp.int32), ((RISK.event_catalog + 1, M),
-                                        jnp.float32),
-        (lay + (M,), jnp.float32), (lay + (M,), jnp.float32),
-        (lay, jnp.float32), (lay, jnp.float32), kernel=kernel)
+        lambda *a: agg.aggregate_loss_pallas(*a, interpret=False),
+        sharding, *_risk_shapes(K, layers), kernel=kernel)
     # the whole program, any relaid table copy included, fits one chip
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     return mem
 
 
-@pytest.mark.parametrize("variant", agg.VARIANTS)
-def test_aggregate_loss_compiles(one_chip, no_compile_cache, variant):
-    mem = _compile_aggregate_loss(one_chip, variant)
-    if variant == "gather":
-        # the VMEM lookup: no lane-padded copy of the 120 MB ELT, only the
-        # combined occurrence losses (8 MB) and the YLT
-        assert agg.lookup_path(RISK.event_catalog + 1) == "vmem"
-        assert mem.temp_size_in_bytes < 64e6
+def test_aggregate_loss_compiles(one_chip, no_compile_cache):
+    mem = _compile_aggregate_loss(one_chip)
+    # the VMEM lookup: no lane-padded copy of the 120 MB ELT, only the
+    # combined occurrence losses (8 MB) and the YLT
+    assert agg.lookup_path(RISK.event_catalog + 1) == "vmem"
+    assert mem.temp_size_in_bytes < 64e6
+
+
+@pytest.mark.parametrize("layers", [None, 8])
+def test_risk_step_compiles(one_chip, no_compile_cache, layers):
+    """``kernels.ops.aggregate_loss`` as the risk step calls it: the
+    unpadded 1,000 events a trial, padded to a chunk multiple in the step,
+    one Pallas kernel, the VMEM lookup, for one layer and a tower of 8."""
+    from repro.kernels import ops as kops
+    prev = kops.pallas_enabled()
+    kops.use_pallas(True, interpret=False)
+    try:
+        assert kops.aggregate_lookup(RISK.event_catalog + 1, layers) == (
+            "vmem" if layers is None else f"vmem.layers{layers}")
+        compiled = _compile(
+            lambda *a: kops.aggregate_loss(*a, chunk=RISK.chunk_events),
+            one_chip, *_risk_shapes(RISK.events_per_trial, layers))
+    finally:
+        kops.use_pallas(prev)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
 def test_tower_lookup_compiles(one_chip, no_compile_cache):
@@ -125,7 +148,7 @@ def test_tower_lookup_compiles(one_chip, no_compile_cache):
     in one VMEM lookup, the 64 MiB table within the budget, and no copy of
     the table padded to 128 lanes a catalog row (1 GB)."""
     assert agg.lookup_path(RISK.event_catalog + 1, 8) == "vmem"
-    mem = _compile_aggregate_loss(one_chip, "gather", layers=8)
+    mem = _compile_aggregate_loss(one_chip, layers=8)
     assert mem.output_size_in_bytes == 4 * RISK_TRIALS * 8
     assert mem.temp_size_in_bytes < 200e6
 
@@ -140,7 +163,7 @@ def test_vmem_chunked_id_blocks_compile(one_chip, no_compile_cache,
     K = -(-RISK.events_per_trial // RISK.chunk_events) * RISK.chunk_events
     assert 4 * 8 * K > agg.LOOKUP_SMEM_BUDGET
     assert agg.lookup_path(RISK.event_catalog + 1, layers or 1) == "vmem"
-    mem = _compile_aggregate_loss(one_chip, "gather", layers=layers)
+    mem = _compile_aggregate_loss(one_chip, layers=layers)
     assert mem.temp_size_in_bytes < (64e6 if layers is None else 200e6)
 
 
@@ -148,13 +171,13 @@ def test_aggregate_loss_xla_fallback_compiles(one_chip, no_compile_cache,
                                               monkeypatch):
     monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 1 << 20)
     assert agg.lookup_path(RISK.event_catalog + 1) == "xla"
-    _compile_aggregate_loss(one_chip, "gather", kernel=False)
+    _compile_aggregate_loss(one_chip, kernel=False)
 
 
 def test_tower_xla_fallback_compiles(one_chip, no_compile_cache, monkeypatch):
     monkeypatch.setattr(agg, "LOOKUP_VMEM_BUDGET", 16 << 20)
     assert agg.lookup_path(RISK.event_catalog + 1, 8) == "xla"
-    _compile_aggregate_loss(one_chip, "gather", kernel=False, layers=8)
+    _compile_aggregate_loss(one_chip, kernel=False, layers=8)
 
 
 def test_ssd_scan_compiles(one_chip, no_compile_cache):
