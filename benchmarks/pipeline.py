@@ -111,14 +111,15 @@ def bench_pipeline_overlap() -> List[Row]:
     out: List[Row] = []
     t_ovl = _best_of(lambda: ara.run_tenant_chunked(tables), n=9)
     tag = f"{n_pdev}p_2v"
-    from repro.core.pipeline import timeline_overlaps
+    from repro.core.pipeline import lane_overlaps
     rep = ara.run_tenant_chunked(tables)
     # falsifiable overlap signal: transfer(k+1) began inside compute(k)'s
-    # execution window (see repro.core.pipeline module docstring).  A
-    # schedule that staged everything first would score 0 pairs; noise on a
-    # shared host can drain isolated pairs early, so "realised" = majority
-    # of pairs overlapped.
-    overlaps = timeline_overlaps(rep.timeline)
+    # execution window, k and k+1 consecutive tenants of one pdev's lane
+    # (see repro.core.pipeline module docstring).  A schedule that staged
+    # everything first would score 0 pairs; noise on a shared host can
+    # drain isolated pairs early, so "realised" = majority of pairs
+    # overlapped.
+    overlaps = lane_overlaps(rep.timeline)
     out.append((f"pipeline/overlapped_{tag}", t_ovl * 1e6,
                 f"trials={cfg.num_trials};"
                 f"overlap_pairs={sum(overlaps)}/{len(overlaps)};"

@@ -1,21 +1,29 @@
 """Overlapped multi-tenant execution pipeline (paper Figs 11/13, executable).
 
 The simulator in :mod:`repro.core.simulator` *models* the paper's winning
-schedule: with sequential transfers, tenant k+1's host->device staging rides
-the link while tenant k's compute occupies its pdev, so the makespan is
-``max(transfer chain, compute chains)`` instead of their sum.  A schedule
-that staged every tenant chunk before dispatching any compute would take
-``sum(transfers) + compute`` and never show that overlap.
+schedule on its one shared network link: with sequential transfers, tenant
+k+1's host->device staging rides the link while tenant k's compute occupies
+its pdev, so the makespan is ``max(transfer chain, compute chains)`` instead
+of their sum.  A schedule that staged every tenant chunk before dispatching
+any compute would take ``sum(transfers) + compute`` and never show that
+overlap.
 
 :class:`PipelineExecutor` is the executable counterpart of the simulated
 schedule — the **simulator-vs-executable overlap contract**:
 
-* sequential mode — chunks are staged one at a time (each transfer owns the
-  full link, paper Fig 10); the moment chunk k is device-resident its jitted
-  compute is *dispatched* (asynchronously) and the executor immediately
-  starts staging chunk k+1.  Transfer(k+1) therefore overlaps compute(k),
-  which is exactly the double-buffering the simulator's ``simulate()``
-  timeline assumes.
+* sequential mode — staging is sequential *per lane*.  A lane is the tasks
+  of one pdev in staging order, and it rides that pdev's own host->device
+  link (on a TPU host every chip has its own DMA path; the paper's single
+  shared link is what the simulator models).  At most one chunk of a lane
+  is in flight, so each transfer has its link to itself (paper Fig 10);
+  lanes run at the same time.  One dispatcher thread drives every lane,
+  because ``put`` is asynchronous: it enqueues each lane's head chunk, then,
+  in staging order, waits for a lane's chunk, *dispatches* its jitted
+  compute (asynchronously) and at once enqueues that lane's next chunk.
+  Transfer(k+1) of a lane therefore overlaps compute(k) of the same lane,
+  which is the double-buffering the simulator's ``simulate()`` timeline
+  assumes.  With one pdev there is one lane, and the calls are put, wait,
+  dispatch, put, wait, dispatch, ...
 * concurrent mode — every transfer is enqueued up front (streams share the
   link, BW/n each, Fig 8); each tenant's compute is dispatched as soon as its
   chunk lands, in staging order.
@@ -31,7 +39,7 @@ entries carry per-tenant ``transfer_start/transfer_end/compute_start/
 compute_end`` wall-clock timestamps (relative to run start).  A dedicated
 waiter thread blocks on each tenant's output *concurrently with the staging
 loop* and stamps ``compute_end`` the moment the output is ready, so the
-realised-overlap signal used by :meth:`PipelineReport.overlaps` —
+realised-overlap signal —
 
     ``compute_start(k) <= transfer_start(k+1) < compute_end(k)``
 
@@ -39,16 +47,24 @@ realised-overlap signal used by :meth:`PipelineReport.overlaps` —
 both directions: a schedule that staged everything before any compute
 would fail the left inequality (every transfer would precede every compute;
 this rejection is structural, independent of timing noise), and a dispatch whose compute
-drained before the next chunk was staged fails the right one.  One
-measurement caveat on the right inequality: ``compute_end`` is stamped at
-waiter-thread wakeup, so gaps shorter than a thread wakeup (~tens of µs)
-are not resolved — the signal is meaningful for ms-scale tenant computes,
-not µs-scale toys.  There is one waiter per pdev, and a pdev's tenants
-complete in dispatch order (its device stream serialises them), so the
-stamps carry no cross-pdev ordering skew.
+drained before the next chunk was staged fails the right one.
+:func:`timeline_overlaps` applies it to consecutive entries of one
+timeline (serving's single-engine timelines); :func:`lane_overlaps`, which
+:meth:`PipelineReport.overlaps` uses, applies it to consecutive tenants of
+the same pdev, since across lanes transfers start before the previous
+lane's compute by design.  One measurement caveat on the right inequality:
+``compute_end`` is stamped at waiter-thread wakeup, so gaps shorter than a
+thread wakeup (~tens of µs) are not resolved — the signal is meaningful
+for ms-scale tenant computes, not µs-scale toys.  There is one waiter per
+pdev, and a pdev's tenants complete in dispatch order (its device stream
+serialises them), so the stamps carry no cross-pdev ordering skew.
+
+Counter (enabled plane): ``transfer.lanes_parallel_puts``, each put enqueued
+while another lane's chunk is still in flight — 0 with one pdev.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import queue
@@ -90,11 +106,20 @@ def timeline_overlaps(timeline: Sequence[TenantTimeline]) -> List[bool]:
     """For each consecutive staged pair (k, k+1): did tenant k+1's transfer
     start *inside* tenant k's compute window?  All-True on a multi-tenant
     sequential run means the paper's overlap is realised (see the module
-    docstring for why this predicate is falsifiable).  Shared by
-    :class:`PipelineReport` and the benchmark harness (which reads the same
-    timeline off a risk ``RunReport``)."""
+    docstring for why this predicate is falsifiable).  Serving's
+    single-engine timelines use it as it is; the executor's, which stage one
+    lane per pdev, go through :func:`lane_overlaps`."""
     return [a.compute_start <= b.transfer_start < a.compute_end
             for a, b in zip(timeline, timeline[1:])]
+
+
+def lane_overlaps(timeline: Sequence[TenantTimeline]) -> List[bool]:
+    """:func:`timeline_overlaps` over each pdev's lane: the pairs are
+    consecutive tenants of the same pdev, in timeline order."""
+    lanes: Dict[int, List[TenantTimeline]] = {}
+    for e in timeline:
+        lanes.setdefault(e.pdev, []).append(e)
+    return [ok for lane in lanes.values() for ok in timeline_overlaps(lane)]
 
 
 @dataclasses.dataclass
@@ -108,11 +133,11 @@ class PipelineReport:
         return {tl.vdev: tl.compute_s for tl in self.timeline}
 
     def overlaps(self) -> List[bool]:
-        return timeline_overlaps(self.timeline)
+        return lane_overlaps(self.timeline)
 
     def overlap_realised(self) -> bool:
-        # majority-of-pairs, matching every live consumer of
-        # timeline_overlaps (benchmarks + tests): noise on a shared host can
+        # majority-of-pairs, matching every live consumer of the overlap
+        # predicate (benchmarks + tests): noise on a shared host can
         # legitimately drain isolated pairs early, while a blocking schedule
         # structurally scores zero pairs
         ov = self.overlaps()
@@ -181,13 +206,15 @@ class CompletionWaiter:
 
 
 class PipelineExecutor:
-    """Event-driven executor: stage chunk k+1 while chunk k computes.
+    """Event-driven executor: stage chunk k+1 while chunk k computes, one
+    staging lane per pdev.
 
     The executor owns a :class:`StagingEngine` (for placement + the staging
     log) and drives its non-blocking ``put`` and blocking ``wait``,
-    interleaving compute dispatch with the transfer chain.  Spans: ``pipeline.start`` around starting the waiter
-    threads, ``pipeline.dispatch`` around each compute call,
-    ``pipeline.drain`` while the host waits for the last outputs.
+    interleaving compute dispatch with the lanes' transfer chains.  Spans:
+    ``pipeline.start`` around starting the waiter threads,
+    ``pipeline.dispatch`` around each compute call, ``pipeline.drain``
+    while the host waits for the last outputs.
     """
 
     def __init__(self, pool: VirtualDevicePool, mode: Optional[str] = None,
@@ -247,10 +274,28 @@ class PipelineExecutor:
 
         try:
             if self.mode == "sequential":
-                # one transfer on the link at a time; compute(k) is already
-                # in flight while put+wait stages chunk k+1 (double buffering)
+                # one transfer at a time on each pdev's link, the lanes at
+                # once; compute(k) of a lane is in flight while its chunk
+                # k+1 stages (double buffering)
+                lanes: Dict[int, collections.deque] = {}
                 for task in order:
-                    dispatch(task, self.engine.put(task, stage_fn(task), t0))
+                    lanes.setdefault(task.pdev, collections.deque()).append(
+                        task)
+                in_flight: collections.deque = collections.deque()
+
+                def put(task: TenantTask) -> None:
+                    if in_flight:
+                        self.tel.count("transfer.lanes_parallel_puts")
+                    in_flight.append(
+                        (task, self.engine.put(task, stage_fn(task), t0)))
+
+                for lane in lanes.values():
+                    put(lane.popleft())
+                while in_flight:
+                    task, chunk = in_flight.popleft()
+                    dispatch(task, chunk)
+                    if lanes[task.pdev]:
+                        put(lanes[task.pdev].popleft())
             else:
                 # all transfers share the link from t~0; dispatch each
                 # tenant's compute as its chunk lands, in staging order

@@ -3,9 +3,13 @@
 Two modes:
   * CONCURRENT — enqueue every tenant chunk at once; all transfers share the
     host link (each attains ~BW/n, Fig 8/10).
-  * SEQUENTIAL — enqueue chunks one at a time in slot-major tenant order;
-    each transfer gets full link bandwidth and tenant k's compute overlaps
-    tenant k+1's staging (the paper's winning strategy).
+  * SEQUENTIAL — one chunk at a time on each link; each transfer gets its
+    link's full bandwidth and tenant k's compute overlaps tenant k+1's
+    staging (the paper's winning strategy).  The paper's nodes share one
+    network link, which :mod:`repro.core.simulator` models; in the executor
+    (:mod:`repro.core.pipeline`) a link is one pdev's own host->device path,
+    so sequential staging is per lane: one chunk in flight per pdev, the
+    pdevs' lanes at the same time.
 
 `jax.device_put` is asynchronous, so SEQUENTIAL staging naturally overlaps
 the already-dispatched tenant's compute.  The engine records per-chunk wall
@@ -14,7 +18,8 @@ times for the benchmark harness.
 The engine's API is the non-blocking :meth:`StagingEngine.put` and the
 blocking :meth:`StagingEngine.wait`, which the overlapped executor in
 :mod:`repro.core.pipeline` interleaves with compute dispatch (the paper's
-winning schedule).
+winning schedule); the engine itself holds no order, so the executor's
+lanes decide what is in flight.
 
 Spans: ``transfer.put`` around enqueueing a chunk, ``transfer.wait``
 around blocking until it is device-resident; ``transfer.stage`` is the

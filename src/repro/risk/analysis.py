@@ -13,7 +13,8 @@ Three execution paths over the same numerics (kernels/ops.aggregate_loss):
   :class:`repro.core.pipeline.PipelineExecutor`: tenant k's jitted compute is
   dispatched the moment its chunk is device-resident, so tenant k+1's staging
   overlaps tenant k's compute (the paper's winning schedule, Fig 13) and each
-  pdev's execution stream serialises its tenants.
+  pdev's execution stream serialises its tenants.  Each pdev stages over its
+  own lane, one chunk in flight a lane, the lanes at once.
 * ``make_sharded_step`` — pjit over a mesh (trials sharded over every mesh
   axis) for the production dry-run; this is the "beyond-paper" scale-out.
 

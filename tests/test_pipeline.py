@@ -4,7 +4,9 @@ Covers the simulator-vs-executable overlap contract documented in
 repro.core.pipeline: the overlapped run_tenant_chunked must be bit-identical
 to run_single across tenancy configs, must not retrace or re-upload resident
 tables on repeated runs, and its timeline must show tenant k+1's transfer
-starting before tenant k's compute ends.  The multi-device case runs in a
+starting before tenant k's compute ends, k and k+1 on one pdev's staging
+lane; the lanes of several pdevs stage at once, one chunk in flight a
+lane.  The multi-device case runs in a
 subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8 (the flag
 must precede jax initialisation, which this process has already done).
 """
@@ -18,8 +20,12 @@ import numpy as np
 import pytest
 
 from repro.configs.risk_app import RiskAppConfig
-from repro.core.pipeline import PipelineExecutor
+from repro.core.pipeline import (PipelineExecutor, PipelineReport,
+                                 TenantTimeline, lane_overlaps,
+                                 timeline_overlaps)
 from repro.core.tenancy import TenancyConfig, VirtualDevicePool
+from repro.core.transfer import StagedChunk
+from repro.obs.telemetry import Telemetry
 from repro.risk.analysis import AggregateRiskAnalysis
 from repro.risk.tables import generate
 
@@ -208,6 +214,105 @@ def test_executor_reaps_waiter_on_stage_error():
                    for th in threading.enumerate())
 
 
+class _RecordingEngine:
+    """Stands in for the executor's StagingEngine: records each put and
+    wait as (call, pdev, slot) and stages the host tree as it is."""
+
+    def __init__(self, calls):
+        self.calls = calls
+        self.log = []
+
+    def put(self, task, host_tree, t0=None):
+        self.calls.append(("put", task.pdev, task.slot))
+        return StagedChunk(task, host_tree, 0.0)
+
+    def wait(self, chunk, t0=None):
+        self.calls.append(("wait", chunk.task.pdev, chunk.task.slot))
+        return chunk
+
+
+def _record_sequential(n_pdev, tenants):
+    """One sequential run over n_pdev x tenants on the recording engine:
+    the calls in order, and the plane's counters."""
+    pool = VirtualDevicePool(TenancyConfig(n_pdev, tenants, "sequential"))
+    tel = Telemetry(enabled=True)
+    ex = PipelineExecutor(pool, telemetry=tel)
+    calls = []
+    ex.engine = _RecordingEngine(calls)
+
+    def compute(t, x):
+        calls.append(("dispatch", t.pdev, t.slot))
+        return x
+
+    tasks = pool.plan(4 * n_pdev * tenants, uniform=True)
+    rep = ex.run(tasks, lambda t: np.float32([t.vdev]), compute)
+    assert sorted(rep.results) == [t.vdev for t in tasks]
+    return calls, tel.counter_snapshot()
+
+
+def test_lanes_enqueue_every_head_before_the_first_wait():
+    calls, _ = _record_sequential(4, 2)
+    first_wait = next(i for i, c in enumerate(calls) if c[0] == "wait")
+    heads = [calls.index(("put", p, 0)) for p in range(4)]
+    assert max(heads) < first_wait, calls
+    # waits and dispatches follow the staging order, slot-major
+    order = [(p, s) for s in range(2) for p in range(4)]
+    assert [c[1:] for c in calls if c[0] == "wait"] == order
+    assert [c[1:] for c in calls if c[0] == "dispatch"] == order
+
+
+def test_lane_holds_one_chunk_in_flight():
+    calls, _ = _record_sequential(4, 2)
+    in_flight = {p: 0 for p in range(4)}
+    for call, pdev, slot in calls:
+        if call == "put":
+            in_flight[pdev] += 1
+            assert in_flight[pdev] == 1, calls
+        elif call == "wait":
+            in_flight[pdev] -= 1
+    for p in range(4):
+        assert calls.index(("put", p, 1)) > calls.index(("wait", p, 0))
+        # the lane's next chunk is enqueued as soon as its compute dispatched
+        assert calls.index(("put", p, 1)) == \
+            calls.index(("dispatch", p, 0)) + 1
+
+
+def test_one_lane_keeps_put_wait_dispatch_order():
+    calls, _ = _record_sequential(1, 2)
+    assert calls == [("put", 0, 0), ("wait", 0, 0), ("dispatch", 0, 0),
+                     ("put", 0, 1), ("wait", 0, 1), ("dispatch", 0, 1)]
+
+
+@pytest.mark.parametrize("n_pdev,parallel_puts", [(1, 0), (4, 7)])
+def test_lanes_parallel_puts_counter(n_pdev, parallel_puts):
+    """Each put enqueued while another lane's chunk is in flight counts:
+    none on one pdev; on four, three of the four heads and every
+    next chunk (the other lanes' chunks are still in flight)."""
+    _, counters = _record_sequential(n_pdev, 2)
+    assert counters.get("transfer.lanes_parallel_puts", 0) == parallel_puts
+
+
+def _tl(pdev, slot, transfer, compute):
+    return TenantTimeline(2 * slot + pdev, pdev, slot, transfer[0],
+                          transfer[1], compute[0], compute[1])
+
+
+def test_lane_overlaps_pairs_tenants_of_one_pdev():
+    """Two lanes staged at once: each lane's second transfer starts inside
+    its first compute, so every lane pair overlaps, while the global
+    staging order pairs chunks of different lanes that do not.  A blocking
+    schedule (every transfer before any compute) scores no pair."""
+    lanes = [_tl(0, 0, (0, 7), (7, 31)), _tl(1, 0, (0, 7), (8, 32)),
+             _tl(0, 1, (7.5, 14), (31, 55)), _tl(1, 1, (8.5, 15), (32, 56))]
+    assert lane_overlaps(lanes) == [True, True]
+    assert not all(timeline_overlaps(lanes))
+    blocking = [_tl(0, 0, (0, 7), (28, 52)), _tl(1, 0, (7, 14), (29, 53)),
+                _tl(0, 1, (14, 21), (52, 76)), _tl(1, 1, (21, 28), (53, 77))]
+    assert lane_overlaps(blocking) == [False, False]
+    rep = PipelineReport({}, lanes, 56.0, "sequential")
+    assert rep.overlaps() == [True, True] and rep.overlap_realised()
+
+
 def test_uniform_plan_shapes():
     pool = VirtualDevicePool(TenancyConfig(2, 2))
     tasks = pool.plan(67, uniform=True)
@@ -245,18 +350,21 @@ MULTI_DEVICE_SCRIPT = textwrap.dedent("""
     # overlap contract on real multi-device: warm, then check the timeline
     # (transfer k+1 inside compute k's window — needs compute that outlasts
     # one staging step, hence the bigger workload).  A blocking schedule
-    # scores 0/15 pairs (its transfers all precede its computes), so a
+    # scores 0/8 pairs (its transfers all precede its computes), so a
     # majority of overlapped pairs distinguishes the schedules even on a
     # noisy shared-CPU host where individual pairs can legitimately drain
-    # early under contention.
-    from repro.core.pipeline import timeline_overlaps
+    # early under contention.  The pairs are consecutive tenants of one
+    # pdev (its staging lane): across lanes, transfers start before the
+    # previous lane's compute by design.
+    from repro.core.pipeline import lane_overlaps
     big = dataclasses.replace(RiskAppConfig().reduced(), num_trials=65536,
                               events_per_trial=64, chunk_events=64)
     tbig = generate(big, seed=0)
     ara = AggregateRiskAnalysis(big, TenancyConfig(8, 2, "sequential"),
                                 devices=devs)
     ara.run_tenant_chunked(tbig)
-    ov = timeline_overlaps(ara.run_tenant_chunked(tbig).timeline)
+    ov = lane_overlaps(ara.run_tenant_chunked(tbig).timeline)
+    assert len(ov) == 8, ov
     assert sum(ov) > len(ov) // 2, ov
     print("MULTI_DEVICE_OK")
 """)
